@@ -11,12 +11,14 @@ bench geometry is deliberately dispatch-dominated: tiny chunks, megabatch
 of one, many megabatches.  On a real mesh the same rows additionally show
 the compute split.
 
-Device count is locked at first jax init, so the parent (already running
-under run.py's single-device jax) spawns ONE child process with
-``XLA_FLAGS=--xla_force_host_platform_device_count=D`` that prints
-``ROW {json}`` lines; a child failure yields no rows rather than a crash
-(run.py's --check tolerates missing ``mesh_*`` rows for exactly this
-single-device-host case).
+On CPU the device count is locked at first jax init, so the parent
+(already running under run.py's single-device jax) spawns ONE child
+process with ``XLA_FLAGS=--xla_force_host_platform_device_count=D`` that
+prints ``ROW {json}`` lines; a child failure yields no rows rather than a
+crash (run.py's --check tolerates missing ``mesh_*`` rows for exactly this
+single-device-host case).  On an accelerator the parent already holds the
+chips and a child could not open them, so the rows run in the parent over
+the real local devices.
 
 Reported rows (D=1 is the stock single-device engine path — the
 apples-to-apples baseline a user actually gets without the knob):
@@ -84,14 +86,24 @@ def _child_rows(*, smoke: bool, devices: int, timeout_s: int) -> list[dict]:
     return rows
 
 
+def _rows(*, smoke: bool, devices: int, timeout_s: int) -> list[dict]:
+    """Forced host devices in a child on CPU; the real local devices in
+    this process on an accelerator (a child could not open the chips)."""
+    import jax
+
+    if jax.default_backend() == "cpu":
+        return _child_rows(smoke=smoke, devices=devices, timeout_s=timeout_s)
+    return _child(smoke)
+
+
 def run():
     """Full leg: D in {1,2,4}, screen + gram + solve grid + collectives."""
-    return _child_rows(smoke=False, devices=4, timeout_s=900)
+    return _rows(smoke=False, devices=4, timeout_s=900)
 
 
 def run_smoke():
     """--quick leg: D in {1,2}, screen passes only."""
-    return _child_rows(smoke=True, devices=2, timeout_s=600)
+    return _rows(smoke=True, devices=2, timeout_s=600)
 
 
 # --------------------------------------------------------------------------
@@ -107,13 +119,11 @@ def _bench(fn, reps: int = 3) -> float:
     return (time.perf_counter() - t0) / reps
 
 
-def _emit(name: str, us: float, derived: str) -> None:
-    print("ROW " + json.dumps(
-        {"name": name, "us_per_call": us, "derived": derived}))
-    sys.stdout.flush()
+def _row(name: str, us: float, derived: str) -> dict:
+    return {"name": name, "us_per_call": us, "derived": derived}
 
 
-def _pass_rows(store, Ds, tag, *, chunk_nnz, chunk_rows, megabatch,
+def _pass_rows(rows, store, Ds, tag, *, chunk_nnz, chunk_rows, megabatch,
                gram_support=None):
     import numpy as np
 
@@ -132,12 +142,12 @@ def _pass_rows(store, Ds, tag, *, chunk_nnz, chunk_rows, megabatch,
                                                   **geometry))
         t_screen[D] = t
         dispatches = n_mega if D <= 1 else -(-n_mega // D)
-        _emit(
+        rows.append(_row(
             f"mesh_screen_pass_D{D}_{tag}", t * 1e6,
             f"{store.nnz / t / 1e6:.1f}Mnnz/s dispatches={dispatches} "
             f"megabatches={n_mega} nnz={store.nnz} "
             f"speedup={t_screen[Ds[0]] / t:.2f}x",
-        )
+        ))
 
     if gram_support is None:
         return
@@ -148,14 +158,14 @@ def _pass_rows(store, Ds, tag, *, chunk_nnz, chunk_rows, megabatch,
                                                    devices=D, **geometry))
         t_gram[D] = t
         dispatches = n_mega if D <= 1 else -(-n_mega // D)
-        _emit(
+        rows.append(_row(
             f"mesh_gram_pass_D{D}_{tag}", t * 1e6,
             f"n_hat={support.size} {store.nnz / t / 1e6:.1f}Mnnz/s "
             f"dispatches={dispatches} speedup={t_gram[Ds[0]] / t:.2f}x",
-        )
+        ))
 
 
-def _solve_rows(Ds, tag, *, E=16, n=32, per_dev_batch=4):
+def _solve_rows(rows, Ds, tag, *, E=16, n=32, per_dev_batch=4):
     """An E-eval lambda grid at per-device batch B: ceil(E/(B*D)) launches.
 
     On a single-core host the solve is compute-bound (the while-loop
@@ -193,15 +203,15 @@ def _solve_rows(Ds, tag, *, E=16, n=32, per_dev_batch=4):
         launches = (metrics.counter("kernel.launches.bcd_solve_batched").value
                     - c0) / 4  # warm-up + 3 reps
         t_by_d[D] = t
-        _emit(
+        rows.append(_row(
             f"mesh_solve_grid_D{D}_{tag}", t * 1e6,
             f"{E / t:.0f}problems/s E={E} n={n} B={per_dev_batch} "
             f"launches={launches:.0f} (ceil(E/(B*D))={-(-E // round_B)}) "
             f"speedup={t_by_d[Ds[0]] / t:.2f}x",
-        )
+        ))
 
 
-def _collectives_row(D: int, tag: str) -> None:
+def _collectives_row(D: int, tag: str) -> dict:
     """The folded diag_collectives probe: compile the finalize-time pooled
     reduction and report its per-device collective bytes from post-SPMD
     HLO — the cross-device cost of the one host merge, as a number."""
@@ -222,7 +232,7 @@ def _collectives_row(D: int, tag: str) -> None:
     fn = jax.jit(lambda t: psum_partials(t, mesh))
     txt = fn.lower(parts).compile().as_text()
     cb = collective_bytes(txt)
-    _emit(
+    return _row(
         f"mesh_collectives_{tag}", 0.0,
         f"devices={D} allreduce={cb['all-reduce'] / 1e3:.1f}kB "
         f"total={cb['total'] / 1e3:.1f}kB ops={cb['n_ops']} "
@@ -230,7 +240,7 @@ def _collectives_row(D: int, tag: str) -> None:
     )
 
 
-def _child(smoke: bool) -> None:
+def _child(smoke: bool) -> list[dict]:
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -243,14 +253,15 @@ def _child(smoke: bool) -> None:
     from repro.sparse import write_corpus
 
     n_dev = jax.local_device_count()
+    rows: list[dict] = []
     if smoke:
         Ds = [d for d in (1, 2) if d <= n_dev]
         corpus = make_corpus(300, 2_000, topics={"t": ["a", "b"]}, seed=0)
         with tempfile.TemporaryDirectory() as d:
             store = write_corpus(corpus, d, shard_nnz=1 << 17)
-            _pass_rows(store, Ds, "smoke", chunk_nnz=2_048, chunk_rows=128,
-                       megabatch=1)
-        return
+            _pass_rows(rows, store, Ds, "smoke", chunk_nnz=2_048,
+                       chunk_rows=128, megabatch=1)
+        return rows
 
     Ds = [d for d in (1, 2, 4) if d <= n_dev]
     n_docs, n_words = 1_200, 6_000
@@ -261,10 +272,11 @@ def _child(smoke: bool) -> None:
     support = np.sort(np.argsort(var)[::-1][:128])
     with tempfile.TemporaryDirectory() as d:
         store = write_corpus(corpus, d, shard_nnz=1 << 19)
-        _pass_rows(store, Ds, tag, chunk_nnz=1_024, chunk_rows=128,
+        _pass_rows(rows, store, Ds, tag, chunk_nnz=1_024, chunk_rows=128,
                    megabatch=1, gram_support=support)
-    _solve_rows(Ds, tag)
-    _collectives_row(Ds[-1], tag)
+    _solve_rows(rows, Ds, tag)
+    rows.append(_collectives_row(Ds[-1], tag))
+    return rows
 
 
 if __name__ == "__main__":
@@ -275,7 +287,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()
     if args.child:
-        _child(args.smoke)
+        for row in _child(args.smoke):
+            print("ROW " + json.dumps(row), flush=True)
     else:
         for row in (run_smoke() if args.smoke else run()):
             print(f"{row['name']},{row['us_per_call']:.1f},{row['derived']}")

@@ -509,11 +509,16 @@ def solve_bcd_many(
     # pure function of its inputs, so a narrower grid changes launch
     # economics only, never the solves.
     D = min(max(int(devices or 0), 0), B)
+    # the path ops.bcd_solve_batched takes, named like solve_bcd's spans
+    ran = "fused" if impl == "pallas" or (
+        impl == "auto" and _resolve_solver_impl(
+            "auto", n_pad, np.dtype(Sp.dtype).itemsize, B) == "fused"
+    ) else "fused_ref"
     while True:
         span_name = "solver.device_grid" if D > 1 else "solver.solve_many"
         kw = {"devices": D} if D > 1 else {}
         try:
-            with trace.span(span_name, batch=B, n_pad=n_pad, impl=impl,
+            with trace.span(span_name, batch=B, n_pad=n_pad, impl=ran,
                             **kw):
                 X, kernel_objs, sweeps, hist = _dispatch(D)
             break

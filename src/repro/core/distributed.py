@@ -23,12 +23,6 @@ from jax.sharding import PartitionSpec as P
 
 from .elimination import Screen
 
-# jax.shard_map graduated from jax.experimental in newer releases; take
-# whichever this jax provides.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def data_axes_of(mesh: Mesh) -> tuple[str, ...]:
     """All mesh axes that shard documents (everything except 'model')."""
@@ -48,7 +42,7 @@ def _pooled_fn(mesh: Mesh, axes: tuple, ndims: tuple):
     in_specs = tuple(P(axes, *(None,) * (nd - 1)) for nd in ndims)
     out_specs = tuple(P(*(None,) * (nd - 1)) for nd in ndims)
     return jax.jit(
-        _shard_map(pool, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+        jax.shard_map(pool, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     )
 
 
@@ -88,7 +82,7 @@ def distributed_variances(A, mesh: Mesh, *, center: bool = True) -> Screen:
         cnt = jnp.full((1, 1), a.shape[0], a.dtype)
         return s, ss, cnt
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec_in,),
         out_specs=(P(axes, None), P(axes, None), P(axes, None)),
     )
@@ -113,7 +107,7 @@ def distributed_gram(A_red, mesh: Mesh, *, means=None) -> jax.Array:
         cnt = jnp.full((1, 1), a.shape[0], a.dtype)
         return g, cnt
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec_in,),
         out_specs=(P(axes, None, None), P(axes, None)),
     )

@@ -69,14 +69,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+# Scoped VMEM the tiled scheme asks the compiler for.  The 16 MiB default
+# stops the resident X at n_pad 1536 (v5e compiler: n_pad 1664 / R 128
+# needs 16.3 MiB); the core has 128 MiB.  `ops.plan_fused_solve` budgets
+# below this.
+TILED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
 def _pad128(n: int) -> int:
     return max(128, ((n + 127) // 128) * 128)
+
+
+def _pick(vec, lanes, i):
+    """``vec[0, i]`` of a (1, n) row as a (1, 1) value.  A traced lane index
+    has no vector lowering on TPU, so the pick is a one-hot masked lane
+    reduction (exact: every other term is 0)."""
+    return jnp.sum(jnp.where(lanes == i, vec, 0.0), axis=1, keepdims=True)
+
+
+def _sum11(x):
+    """Full sum of a 2-D value, kept as a (1, 1) vector."""
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
 
 
 def _solve_tau(R2, c, beta, tau_iters):
     """min_{tau>0} R2/tau - beta*log(tau) + (c + tau)^2 / 2 — bisection on
     the strictly increasing derivative (branch-free, shared by both
-    kernels; mirrors `core.bcd.solve_tau`)."""
+    kernels; mirrors `core.bcd.solve_tau`).  ``R2``/``c`` are (1, 1)."""
     hi = jnp.maximum(1.0, -c) + jnp.sqrt(jnp.maximum(R2, 0.0)) + beta + 1.0
     lo = jnp.minimum(beta / (beta + jnp.maximum(-c, 0.0) + 1.0), hi) * 1e-12
 
@@ -92,20 +112,130 @@ def _solve_tau(R2, c, beta, tau_iters):
     return 0.5 * (lo + hi)
 
 
-def _coord_update(i, u, w, col, s, lam, j):
-    """One closed-form (13) coordinate update given Y's column i (``col``)."""
-    y1 = col[i]
-    ui = u[i]
-    g = w[i] - y1 * ui                          # \hat y^T \hat u
-    lo = s[i] - lam
-    hi = s[i] + lam
-    eta_pos = jnp.clip(-g / jnp.where(y1 > 0, y1, 1.0), lo, hi)
-    eta_zero = jnp.where(g > 0, lo, hi)
-    eta = jnp.where(y1 > 0, eta_pos, eta_zero)
-    eta = jnp.where(i == j, ui, eta)            # coordinate j is pinned
-    w = w + col * (eta - ui)
-    u = jax.lax.dynamic_update_slice(u, eta[None], (i,))
-    return u, w
+def _solve(x_ref, hist_ref, meta_ref, *, n_pad, R, lam, beta, tol, n_valid,
+           max_sweeps, qp_sweeps, tau_iters, sweep_rows, sigma_x):
+    """Algorithm 1 on the VMEM-resident iterate ``x_ref[0]`` (n_pad, n_pad),
+    shared by both schemes.  ``sweep_rows(row_update, dX)`` runs one sweep,
+    calling ``row_update(j, sigma_row_j, dX)`` for j < n_valid in order;
+    ``sigma_x()`` returns Tr(Sigma X) as (1, 1).  X-wide work walks
+    R-row panels (static slices); every row vector is (1, n_pad) with the
+    coordinate on the lane axis.
+
+    ``dX`` carries diag(X) as a (1, n_pad) row: a row update changes only
+    X_jj on the diagonal, so Tr X and Tr Y never re-read the matrix."""
+    dtype = hist_ref.dtype
+    n_panels = n_pad // R
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1)
+    lane128 = jax.lax.broadcasted_iota(jnp.int32, (R, 128), 1)
+
+    def panel(p):
+        return x_ref[0, p * R:(p + 1) * R, :]
+
+    def x_matvec(s):
+        """s X as a (1, n_pad) row (= (X s)^T: X is symmetric)."""
+        s8 = jnp.broadcast_to(s, (8, n_pad))
+        acc = jnp.zeros((8, n_pad), jnp.float32 if dtype == jnp.float32
+                        else dtype)
+        for p in range(n_panels):
+            acc = acc + jnp.dot(s8[:, p * R:(p + 1) * R], panel(p),
+                                precision=_HIGHEST,
+                                preferred_element_type=acc.dtype)
+        return acc[0:1].astype(dtype)
+
+    def write_row_and_col(j, newrow):
+        """X[j, :] = X[:, j] = newrow.  The column lands through the
+        128-lane tile holding j (an aligned dynamic lane window); the
+        transposed values come from a (128, R) -> (R, 128) transpose."""
+        jt = pl.multiple_of((j // 128) * 128, 128)
+        for p in range(n_panels):
+            colT = jnp.transpose(
+                jnp.broadcast_to(newrow[:, p * R:(p + 1) * R], (128, R)))
+            rows = slice(p * R, (p + 1) * R)
+            tile = x_ref[0, rows, pl.ds(jt, 128)]
+            x_ref[0, rows, pl.ds(jt, 128)] = jnp.where(
+                lane128 == j - jt, colT, tile)
+        x_ref[0, pl.ds(j, 1), :] = newrow
+
+    def row_update(j, srow, dX):
+        mfb = (lanes != j) & (lanes < n_valid)
+        s = jnp.where(mfb, srow, 0.0)               # Sigma_j, masked
+        t = _sum11(dX) - _pick(dX, lanes, j)        # Tr Y = Tr X - X_jj
+        c = _pick(srow, lanes, j) - lam - t
+        dY = jnp.where(mfb, dX, 0.0)                # diag(Y)
+        pos = dY > 0
+        div = jnp.where(pos, dY, 1.0)
+        lo = s - lam
+        hi = s + lam
+        free = mfb                                  # j pinned, pad frozen
+
+        def coord_step(i, carry):
+            u, w = carry
+            # BCD preserves symmetry, so Y's column i is X's ROW i masked:
+            # a contiguous lane load.  eta is formed on every lane (the
+            # closed form (13)) and lane i is kept.
+            col = jnp.where(mfb, x_ref[0, pl.ds(i, 1), :], 0.0)
+            g = w - dY * u                          # \hat y^T \hat u
+            eta = jnp.where(pos, jnp.clip(-g / div, lo, hi),
+                            jnp.where(g > 0, lo, hi))
+            hit = (lanes == i) & free
+            delta = jnp.sum(jnp.where(hit, eta - u, 0.0), axis=1,
+                            keepdims=True)
+            return jnp.where(hit, eta, u), w + col * delta
+
+        def qp_sweep(_, carry):
+            return jax.lax.fori_loop(0, n_valid, coord_step, carry)
+
+        # w0 = Y s = mf o (X s): s is pre-masked, so column j and the
+        # padding never contribute; masking the product removes row j.
+        w0 = jnp.where(mfb, x_matvec(s), 0.0)
+        u, w = jax.lax.fori_loop(0, qp_sweeps, qp_sweep, (s, w0))
+        tau = _solve_tau(_sum11(u * w), c, beta, tau_iters)
+        # X differs from Y + outer products ONLY in row j / column j.
+        ej = lanes == j
+        xjj = c + tau
+        write_row_and_col(j, jnp.where(ej, xjj, w / tau))
+        return jnp.where(ej, xjj, dX)
+
+    def partial_obj(dX):
+        tr = _sum11(dX)
+        l1 = jnp.zeros((1, 1), dtype)
+        for p in range(n_panels):
+            l1 = l1 + _sum11(jnp.abs(panel(p)))
+        return sigma_x() - lam * l1 - 0.5 * tr * tr
+
+    sub = jax.lax.broadcasted_iota(jnp.int32, (R, n_pad), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, n_pad), 1)
+    dX0 = jnp.zeros((1, n_pad), dtype)
+    for p in range(n_panels):
+        dX0 = dX0 + jnp.sum(jnp.where(sub + p * R == lane, panel(p), 0.0),
+                            axis=0, keepdims=True)
+    hlanes = jax.lax.broadcasted_iota(jnp.int32, hist_ref.shape[1:], 1)
+    hist_ref[0] = jnp.full(hist_ref.shape[1:], jnp.nan, dtype)
+
+    def cond(state):
+        k, done, _, _ = state
+        return (done == 0) & (k < max_sweeps)
+
+    def body(state):
+        k, _, dX, prev = state
+        dX = sweep_rows(row_update, dX)
+        obj = partial_obj(dX)
+        hist_ref[0] = jnp.where(hlanes == k, obj, hist_ref[0])
+        done = jnp.abs(obj - prev) <= tol * (1.0 + jnp.abs(obj))
+        return k + 1, jnp.max(done.astype(jnp.int32)), dX, obj
+
+    minus_inf = jnp.full((1, 1), -jnp.inf, dtype)
+    k, _, _, obj = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), jnp.int32(0), dX0, minus_inf))
+    mlanes = jax.lax.broadcasted_iota(jnp.int32, meta_ref.shape[1:], 1)
+    kf = jnp.full(meta_ref.shape[1:], k, jnp.int32).astype(dtype)
+    meta_ref[0] = jnp.where(mlanes == 0, obj, jnp.where(mlanes == 1, kf, 0.0))
+
+
+def _scalars(scal_ref, nv_ref):
+    b = pl.program_id(0)
+    return dict(lam=scal_ref[4 * b], beta=scal_ref[4 * b + 1],
+                tol=scal_ref[4 * b + 2], n_valid=nv_ref[b])
 
 
 # ---------------------------------------------------------------------------
@@ -114,79 +244,23 @@ def _coord_update(i, u, w, col, s, lam, j):
 
 
 def _bcd_resident_kernel(
-    sig_ref, x0_ref, scal_ref, x_ref, hist_ref, meta_ref,
-    *, n_pad, hist_pad, max_sweeps, qp_sweeps, tau_iters,
+    scal_ref, nv_ref, sig_ref, x0_ref, x_ref, hist_ref, meta_ref, *, n_pad,
+    max_sweeps, qp_sweeps, tau_iters,
 ):
-    Sigma = sig_ref[0]
-    dtype = Sigma.dtype
-    lam = scal_ref[0, 0]
-    beta = scal_ref[0, 1]
-    n_valid = scal_ref[0, 2].astype(jnp.int32)
-    tol = scal_ref[0, 3]
+    sc = _scalars(scal_ref, nv_ref)
+    x_ref[0] = x0_ref[0]
 
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)[:, 0]
-    ri = jax.lax.broadcasted_iota(jnp.int32, (n_pad, n_pad), 0)
-    ci = jax.lax.broadcasted_iota(jnp.int32, (n_pad, n_pad), 1)
-    eyem = (ri == ci).astype(dtype)                 # diagonal mask
+    def sweep_rows(row_update, dX):
+        return jax.lax.fori_loop(
+            0, sc["n_valid"],
+            lambda j, dX: row_update(j, sig_ref[0, pl.ds(j, 1), :], dX), dX)
 
-    def coord_step(i, carry, Y, s, j):
-        u, w = carry
-        col = jax.lax.dynamic_slice(Y, (jnp.int32(0), i), (n_pad, 1))[:, 0]
-        return _coord_update(i, u, w, col, s, lam, j)
+    def sigma_x():
+        return _sum11(sig_ref[0] * x_ref[0])
 
-    def row_update(j, X):
-        col = jax.lax.dynamic_slice(Sigma, (jnp.int32(0), j), (n_pad, 1))[:, 0]
-        mf = ((idx != j) & (idx < n_valid)).astype(dtype)
-        Y = X * mf[:, None] * mf[None, :]
-        s = col * mf
-        diag = jnp.sum(X * eyem, axis=1)
-        t = jnp.sum(diag) - diag[j]                 # Tr Y = Tr X - X_jj
-        c = col[j] - lam - t
-
-        def qp_sweep(_, carry):
-            return jax.lax.fori_loop(
-                0, n_valid,
-                functools.partial(coord_step, Y=Y, s=s, j=j), carry,
-            )
-
-        u, w = jax.lax.fori_loop(0, qp_sweeps, qp_sweep, (s, Y @ s))
-        tau = _solve_tau(jnp.dot(u, w), c, beta, tau_iters)
-
-        y = w / tau                                 # zero at j and in padding
-        ejf = ((idx == j) & (idx < n_valid)).astype(dtype)
-        X = Y + y[:, None] * ejf[None, :] + ejf[:, None] * y[None, :]
-        return X + (c + tau) * ejf[:, None] * ejf[None, :]
-
-    def partial_obj(X):
-        tr = jnp.sum(X * eyem)
-        return jnp.sum(Sigma * X) - lam * jnp.sum(jnp.abs(X)) - 0.5 * tr * tr
-
-    def cond(state):
-        _, _, _, _, k, done = state
-        return jnp.logical_not(done) & (k < max_sweeps)
-
-    def body(state):
-        X, hist, prev, _, k, _ = state
-        X = jax.lax.fori_loop(0, n_valid, row_update, X)
-        obj = partial_obj(X)
-        hist = jax.lax.dynamic_update_slice(hist, obj[None], (k,))
-        done = jnp.abs(obj - prev) <= tol * (1.0 + jnp.abs(obj))
-        return X, hist, obj, obj, k + 1, done
-
-    minus_inf = jnp.array(-jnp.inf, dtype)
-    state0 = (
-        x0_ref[0],
-        jnp.full((hist_pad,), jnp.nan, dtype),
-        minus_inf,
-        minus_inf,
-        jnp.array(0, jnp.int32),
-        jnp.array(False),
-    )
-    X, hist, _, obj, k, _ = jax.lax.while_loop(cond, body, state0)
-    x_ref[0] = X
-    hist_ref[0, :] = hist
-    meta_ref[0, 0] = obj
-    meta_ref[0, 1] = k.astype(dtype)
+    _solve(x_ref, hist_ref, meta_ref, n_pad=n_pad, R=n_pad,
+           max_sweeps=max_sweeps, qp_sweeps=qp_sweeps, tau_iters=tau_iters,
+           sweep_rows=sweep_rows, sigma_x=sigma_x, **sc)
 
 
 # ---------------------------------------------------------------------------
@@ -195,141 +269,59 @@ def _bcd_resident_kernel(
 
 
 def _bcd_tiled_kernel(
-    scal_ref, sig_hbm, x0_hbm, x_ref, hist_ref, meta_ref, buf, sem, xsem,
-    *, n_pad, panel_rows, hist_pad, max_sweeps, qp_sweeps, tau_iters,
+    scal_ref, nv_ref, sig_hbm, x0_hbm, x_hbm, hist_ref, meta_ref, x_ref, buf,
+    sem, xsem, *, n_pad, panel_rows, max_sweeps, qp_sweeps, tau_iters,
 ):
     b = pl.program_id(0)
     R = panel_rows
     n_panels = n_pad // R
-    lam = scal_ref[0, 0]
-    beta = scal_ref[0, 1]
-    n_valid = scal_ref[0, 2].astype(jnp.int32)
-    tol = scal_ref[0, 3]
-    dtype = lam.dtype
+    sc = _scalars(scal_ref, nv_ref)
 
-    # X0: HBM -> resident VMEM block, one whole-matrix DMA.
+    # X0: HBM -> the resident VMEM scratch, one whole-matrix DMA (a single
+    # buffer: an X output block would be double-buffered, 2 n_pad^2 words).
     cp = pltpu.make_async_copy(x0_hbm.at[b], x_ref.at[0], xsem)
     cp.start()
     cp.wait()
-
-    idx = jax.lax.broadcasted_iota(jnp.int32, (n_pad, 1), 0)[:, 0]
-    pri = jax.lax.broadcasted_iota(jnp.int32, (R, n_pad), 0)
-    pci = jax.lax.broadcasted_iota(jnp.int32, (R, n_pad), 1)
 
     def get_dma(slot, p):
         return pltpu.make_async_copy(
             sig_hbm.at[b, pl.ds(p * R, R), :], buf.at[slot], sem.at[slot]
         )
 
-    def trace_of_x():
-        """Tr X from the resident block, one R-row panel at a time (never
-        materialises an n_pad^2 temporary)."""
+    def stream(panel_body, init):
+        """One double-buffered pass of Sigma's row panels through VMEM."""
+        get_dma(0, 0).start()
+
         def body(p, acc):
-            rows = x_ref[0, pl.ds(p * R, R), :]
-            dmask = (pci == p * R + pri).astype(dtype)
-            return acc + jnp.sum(rows * dmask)
-        return jax.lax.fori_loop(0, n_panels, body, jnp.array(0.0, dtype))
-
-    def matvec(s):
-        """X @ s via panel row-blocks of the resident X."""
-        def body(p, w):
-            rows = x_ref[0, pl.ds(p * R, R), :]
-            return jax.lax.dynamic_update_slice(w, rows @ s, (p * R,))
-        return jax.lax.fori_loop(0, n_panels, body, jnp.zeros((n_pad,), dtype))
-
-    def coord_step(i, carry, mf, s, j):
-        u, w = carry
-        # BCD preserves symmetry (row j and col j written identically), so
-        # Y's column i is X's ROW i masked — a contiguous lane load.
-        col = x_ref[0, pl.ds(i, 1), :][0] * mf
-        return _coord_update(i, u, w, col, s, lam, j)
-
-    def row_update(r, tr, p):
-        j = p * R + r
-        srow = buf[p % 2, pl.ds(r, 1), :][0]        # Sigma row j, current panel
-        mf = ((idx != j) & (idx < n_valid)).astype(dtype)
-        s = srow * mf
-        xjj = x_ref[0, pl.ds(j, 1), :][0, j]
-        t = tr - xjj                                # Tr Y = Tr X - X_jj
-        c = srow[j] - lam - t
-
-        def qp_sweep(_, carry):
-            return jax.lax.fori_loop(
-                0, n_valid,
-                functools.partial(coord_step, mf=mf, s=s, j=j), carry,
-            )
-
-        # w0 = Y @ s = mf o (X @ s): s is pre-masked, so column j and the
-        # padding never contribute; masking the product removes row j.
-        u, w = jax.lax.fori_loop(0, qp_sweeps, qp_sweep, (s, matvec(s) * mf))
-        tau = _solve_tau(jnp.dot(u, w), c, beta, tau_iters)
-
-        # X differs from Y + outer products ONLY in row j / column j.
-        ejf = ((idx == j) & (idx < n_valid)).astype(dtype)
-        newrow = w / tau + (c + tau) * ejf
-        x_ref[0, pl.ds(j, 1), :] = newrow[None, :]
-        x_ref[0, :, pl.ds(j, 1)] = newrow[:, None]
-        return t + (c + tau)                        # updated Tr X
-
-    def sweep(tr):
-        get_dma(0, 0).start()
-
-        def panel_body(p, tr):
             @pl.when(p + 1 < n_panels)
             def _():
                 get_dma((p + 1) % 2, p + 1).start()
             get_dma(p % 2, p).wait()
-            rows_here = jnp.clip(n_valid - p * R, 0, R)
+            return panel_body(p, acc)
+
+        return jax.lax.fori_loop(0, n_panels, body, init)
+
+    def sweep_rows(row_update, dX):
+        def panel_rows_(p, dX):
+            rows_here = jnp.clip(sc["n_valid"] - p * R, 0, R)
             return jax.lax.fori_loop(
-                0, rows_here, functools.partial(row_update, p=p), tr
-            )
+                0, rows_here,
+                lambda r, dX: row_update(
+                    p * R + r, buf[p % 2, pl.ds(r, 1), :], dX), dX)
+        return stream(panel_rows_, dX)
 
-        return jax.lax.fori_loop(0, n_panels, panel_body, tr)
+    def sigma_x():
+        def acc(p, sx):
+            rows = x_ref[0, pl.ds(pl.multiple_of(p * R, R), R), :]
+            return sx + _sum11(buf[p % 2] * rows)
+        return stream(acc, jnp.zeros((1, 1), x_ref.dtype))
 
-    def partial_obj(tr):
-        """F(X) accumulated panel-wise: one more Sigma pass per sweep."""
-        get_dma(0, 0).start()
-
-        def body(p, accs):
-            sx, l1 = accs
-            @pl.when(p + 1 < n_panels)
-            def _():
-                get_dma((p + 1) % 2, p + 1).start()
-            get_dma(p % 2, p).wait()
-            xrows = x_ref[0, pl.ds(p * R, R), :]
-            sx = sx + jnp.sum(buf[p % 2] * xrows)
-            l1 = l1 + jnp.sum(jnp.abs(xrows))
-            return sx, l1
-
-        zero = jnp.array(0.0, dtype)
-        sx, l1 = jax.lax.fori_loop(0, n_panels, body, (zero, zero))
-        return sx - lam * l1 - 0.5 * tr * tr
-
-    def cond(state):
-        _, _, _, _, k, done = state
-        return jnp.logical_not(done) & (k < max_sweeps)
-
-    def body(state):
-        tr, hist, prev, _, k, _ = state
-        tr = sweep(tr)
-        obj = partial_obj(tr)
-        hist = jax.lax.dynamic_update_slice(hist, obj[None], (k,))
-        done = jnp.abs(obj - prev) <= tol * (1.0 + jnp.abs(obj))
-        return tr, hist, obj, obj, k + 1, done
-
-    minus_inf = jnp.array(-jnp.inf, dtype)
-    state0 = (
-        trace_of_x(),
-        jnp.full((hist_pad,), jnp.nan, dtype),
-        minus_inf,
-        minus_inf,
-        jnp.array(0, jnp.int32),
-        jnp.array(False),
-    )
-    _, hist, _, obj, k, _ = jax.lax.while_loop(cond, body, state0)
-    hist_ref[0, :] = hist
-    meta_ref[0, 0] = obj
-    meta_ref[0, 1] = k.astype(dtype)
+    _solve(x_ref, hist_ref, meta_ref, n_pad=n_pad, R=R,
+           max_sweeps=max_sweeps, qp_sweeps=qp_sweeps, tau_iters=tau_iters,
+           sweep_rows=sweep_rows, sigma_x=sigma_x, **sc)
+    cp = pltpu.make_async_copy(x_ref.at[0], x_hbm.at[b], xsem)
+    cp.start()
+    cp.wait()
 
 
 # ---------------------------------------------------------------------------
@@ -345,72 +337,72 @@ def _bcd_tiled_kernel(
     ),
 )
 def _launch(
-    Sigma3, X03, scal,
+    Sigma3, X03, scal, n_valids,
     *, max_sweeps, qp_sweeps, tau_iters, scheme, panel_rows, interpret,
 ):
     """One `pallas_call` over grid=(B,): B padded problems, either scheme.
 
     ``Sigma3``/``X03`` are (B, n_pad, n_pad) with zeroed padding; ``scal``
-    is (B, 4) rows of [lam, beta, n_valid, tol].
+    is (B, 4) rows of [lam, beta, tol, 0] and ``n_valids`` (B,) int32 — both
+    handed to the kernel in SMEM.  Returns ``(X, hist (B, hist_pad),
+    meta (B, 2) = [obj, sweeps])``.
     """
     B, n_pad, _ = Sigma3.shape
     dtype = Sigma3.dtype
     hist_pad = max(128, ((max_sweeps + 127) // 128) * 128)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out_specs = [
         pl.BlockSpec((1, n_pad, n_pad), lambda b: (b, 0, 0)),
-        pl.BlockSpec((1, hist_pad), lambda b: (b, 0)),
-        pl.BlockSpec((1, 2), lambda b: (b, 0)),
+        pl.BlockSpec((1, 1, hist_pad), lambda b: (b, 0, 0)),
+        pl.BlockSpec((1, 1, 128), lambda b: (b, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, n_pad, n_pad), dtype),
-        jax.ShapeDtypeStruct((B, hist_pad), dtype),
-        jax.ShapeDtypeStruct((B, 2), dtype),
+        jax.ShapeDtypeStruct((B, 1, hist_pad), dtype),
+        jax.ShapeDtypeStruct((B, 1, 128), dtype),
     ]
+    sweeps = dict(max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+                  tau_iters=tau_iters)
     if scheme == "tiled":
         if n_pad % panel_rows:
             raise ValueError(f"{panel_rows=} must divide {n_pad=}")
-        kern = functools.partial(
-            _bcd_tiled_kernel, n_pad=n_pad, panel_rows=panel_rows,
-            hist_pad=hist_pad, max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
-            tau_iters=tau_iters,
-        )
         X, hist, meta = pl.pallas_call(
-            kern,
+            functools.partial(_bcd_tiled_kernel, n_pad=n_pad,
+                              panel_rows=panel_rows, **sweeps),
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, 4), lambda b: (b, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),   # Sigma stays in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),   # X0 stays in HBM
+                smem, smem,
+                pl.BlockSpec(memory_space=pl.ANY),      # Sigma stays in HBM
+                pl.BlockSpec(memory_space=pl.ANY),      # X0 stays in HBM
             ],
-            out_specs=out_specs,
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] + out_specs[1:],
             out_shape=out_shape,
             scratch_shapes=[
+                pltpu.VMEM((1, n_pad, n_pad), dtype),   # resident X
                 pltpu.VMEM((2, panel_rows, n_pad), dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA,
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=TILED_VMEM_LIMIT_BYTES),
             interpret=interpret,
-        )(scal, Sigma3, X03)
+        )(scal.reshape(-1), n_valids, Sigma3, X03)
     elif scheme == "resident":
-        kern = functools.partial(
-            _bcd_resident_kernel, n_pad=n_pad, hist_pad=hist_pad,
-            max_sweeps=max_sweeps, qp_sweeps=qp_sweeps, tau_iters=tau_iters,
-        )
         X, hist, meta = pl.pallas_call(
-            kern,
+            functools.partial(_bcd_resident_kernel, n_pad=n_pad, **sweeps),
             grid=(B,),
             in_specs=[
+                smem, smem,
                 pl.BlockSpec((1, n_pad, n_pad), lambda b: (b, 0, 0)),
                 pl.BlockSpec((1, n_pad, n_pad), lambda b: (b, 0, 0)),
-                pl.BlockSpec((1, 4), lambda b: (b, 0)),
             ],
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
-        )(Sigma3, X03, scal)
+        )(scal.reshape(-1), n_valids, Sigma3, X03)
     else:
         raise ValueError(f"unknown scheme {scheme!r}")
-    return X, hist, meta
+    return X, hist[:, 0], meta[:, 0, :2]
 
 
 def _pad_stack(Sigma3, X03, n_pad):
@@ -449,10 +441,10 @@ def bcd_solve_pallas(
     nv = n if n_valid is None else int(n_valid)
     scal = jnp.stack([
         jnp.asarray(lam, dtype), jnp.asarray(beta, dtype),
-        jnp.asarray(nv, dtype), jnp.asarray(tol, dtype),
+        jnp.asarray(tol, dtype), jnp.zeros((), dtype),
     ])[None, :]
     X, hist, meta = _launch(
-        Sigma3, X03, scal, max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+        Sigma3, X03, scal, jnp.full((1,), nv, jnp.int32), max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
         tau_iters=tau_iters, scheme=scheme, panel_rows=panel_rows,
         interpret=interpret,
     )
@@ -484,11 +476,11 @@ def bcd_solve_batched_pallas(
     scal = jnp.stack([
         jnp.asarray(lams, dtype),
         jnp.broadcast_to(jnp.asarray(betas, dtype), (B,)),
-        jnp.asarray(n_valids, dtype),
         jnp.broadcast_to(jnp.asarray(tol, dtype), (B,)),
+        jnp.zeros((B,), dtype),
     ], axis=1)
     X, hist, meta = _launch(
-        Sigma3, X03, scal, max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+        Sigma3, X03, scal, jnp.asarray(n_valids, jnp.int32), max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
         tau_iters=tau_iters, scheme=scheme, panel_rows=panel_rows,
         interpret=interpret,
     )

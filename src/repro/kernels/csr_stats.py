@@ -50,29 +50,31 @@ def _kernel(vals_ref, cols_ref, sum_ref, sumsq_ref, *, tile_rows: int):
         sumsq_ref[...] = jnp.zeros_like(sumsq_ref)
 
     S = sum_ref.shape[0]
-    v = vals_ref[0].astype(jnp.float32)        # (tile_rows, 128)
-    col = cols_ref[0]                          # (tile_rows, 128) int32
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (S, 128), 0)
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
-
-    def body(a, _):
-        va = jax.lax.dynamic_slice(v, (a, 0), (1, 128))      # (1, 128)
-        ca = jax.lax.dynamic_slice(col, (a, 0), (1, 128))
+    # Static unroll over the block's lane rows (tile_rows <= 8; Mosaic has
+    # no lowering for a dynamic slice of a loaded value).  The rows'
+    # one-hot blocks sit side by side on the lane axis, so the whole block
+    # is ONE contraction over tile_rows * 128 entries.
+    ms, ohls = [], []
+    for a in range(tile_rows):
+        va = vals_ref[0, a:a + 1, :].astype(jnp.float32)     # (1, 128)
+        ca = cols_ref[0, a:a + 1, :]
         ohr = row_iota == ca // 128                          # (S, 128)
-        m = jnp.concatenate(
+        ms.append(jnp.concatenate(
             [jnp.where(ohr, va, 0.0), jnp.where(ohr, va * va, 0.0)], axis=0
-        )                                                    # (2S, 128)
-        ohl = (lane_iota == ca % 128).astype(jnp.float32)    # (128, 128)
-        d = jax.lax.dot_general(
-            m, ohl,
-            dimension_numbers=(((1,), (1,)), ((), ())),      # contract p
-            preferred_element_type=jnp.float32,
-        )                                                    # (2S, 128)
-        sum_ref[...] += d[:S]
-        sumsq_ref[...] += d[S:]
-        return 0
-
-    jax.lax.fori_loop(0, tile_rows, body, 0)
+        ))                                                   # (2S, 128)
+        ohls.append((lane_iota == ca % 128).astype(jnp.float32))
+    # HIGHEST: the one-hot side is exact in bf16 but the values are not; a
+    # default-precision pass would round count^2 sums past 256.
+    d = jax.lax.dot_general(
+        jnp.concatenate(ms, axis=1), jnp.concatenate(ohls, axis=1),
+        dimension_numbers=(((1,), (1,)), ((), ())),          # contract p
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )                                                        # (2S, 128)
+    sum_ref[...] += d[:S]
+    sumsq_ref[...] += d[S:]
 
 
 def csr_column_stats_pallas(
@@ -111,7 +113,9 @@ def csr_column_stats_pallas(
     if pr:
         values = jnp.pad(values, ((0, 0), (0, pr), (0, 0)))
         col_ids = jnp.pad(col_ids, ((0, 0), (0, pr), (0, 0)))
-    n_pad = ((n + 127) // 128) * 128
+    # Accumulator rows S padded to the 8-sublane tile, so the stacked
+    # (sum; sumsq) operand of the contraction stays tile-aligned.
+    n_pad = ((n + 1023) // 1024) * 1024
     S = n_pad // 128
     out_shape = [
         jax.ShapeDtypeStruct((S, 128), jnp.float32),

@@ -29,7 +29,7 @@ from repro.obs import metrics, profile
 from . import ref
 from .bcd_fused import bcd_solve_batched_pallas, bcd_solve_pallas
 from .bcd_sweep import qp_sweep_pallas
-from .csr_gram import batched_gram_fits, csr_gram_batched_pallas, csr_gram_pallas
+from .csr_gram import csr_gram_megabatch_pallas, csr_gram_pallas
 from .csr_stats import csr_column_stats_pallas
 from .gram import gram_pallas
 from .project import sparse_project_pallas
@@ -75,8 +75,8 @@ def _launch(op: str):
     return profile.annotate(f"ops.{op}")
 
 
-# VMEM budgets for the two fused-solve execution schemes, against a ~16 MB/
-# core physical budget.
+# VMEM budgets for the two fused-solve execution schemes, against the scoped
+# VMEM limit the TPU compiler enforces per kernel (16 MiB by default on v5e).
 #
 # resident: Sigma + X in/out blocks plus loop temporaries (Y, the mask outer
 # products) all live on-chip at once — ~4 n_pad^2 words, with headroom for
@@ -84,11 +84,12 @@ def _launch(op: str):
 _RESIDENT_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 # tiled: only X is resident (n_pad^2); Sigma streams through two R x n_pad
 # panel buffers, and the row-update/objective passes touch at most two more
-# panel-sized temporaries plus a handful of n_pad vectors.  The kernel does
-# its own double-buffering, so the budget runs closer to the physical limit.
-# Caps n_hat at ~1664 in f32 (2048 falls back to the XLA program, which
+# panel-sized temporaries plus a handful of n_pad vectors.  The kernel raises
+# its scoped VMEM limit (`bcd_fused.TILED_VMEM_LIMIT_BYTES`, 32 MiB), so the
+# budget sits above the 16 MiB default with headroom below the limit.
+# Caps n_hat at 1664 in f32 (2048 falls back to the XLA program, which
 # handles HBM spilling itself).
-_TILED_VMEM_BUDGET_BYTES = 15 * 1024 * 1024
+_TILED_VMEM_BUDGET_BYTES = 20 * 1024 * 1024
 _PANEL_ROW_CHOICES = (512, 256, 128)    # 128-aligned Sigma panel heights
 
 
@@ -109,20 +110,25 @@ def plan_fused_solve(n: int, itemsize: int = 4, batch: int = 1
     scheme whose accounted VMEM state fits, or ``None`` when no one-launch
     scheme does (the driver then falls back to the XLA program).
 
-    With ``batch > 1`` the grid pipelines the next problem's blocks, so the
-    per-step accounting doubles the revolving buffers (conservatively).
+    With ``batch > 1`` the resident grid pipelines the next problem's
+    blocks, so its accounting doubles the revolving buffers
+    (conservatively); the tiled scheme's state does not revolve.
     """
     n_pad = max(128, ((n + 127) // 128) * 128)
     x_mult = 1 if batch == 1 else 2
     # resident blocks: Sigma in + X0 in + X out (each revolving under a
-    # batch grid) plus the Y temporary of the row update.
+    # batch grid) plus one n_pad^2 of loop temporaries.
     resident = (3 * x_mult + 1) * n_pad * n_pad * itemsize
     if resident <= _RESIDENT_VMEM_BUDGET_BYTES:
         return SolvePlan("resident", n_pad, 0, resident)
     for R in _PANEL_ROW_CHOICES:
         if n_pad % R:
             continue
-        words = x_mult * n_pad * n_pad + 4 * R * n_pad + 16 * n_pad
+        # X is a single VMEM scratch whatever the batch (it DMAs in and out
+        # itself) plus half again of loop temporaries; two Sigma panel
+        # buffers.  Calibrated against the scoped VMEM the v5e compiler
+        # reports for the kernel (n_pad 1536 / R 128: 14.1 MiB; 1664: 16.3).
+        words = 3 * n_pad * n_pad // 2 + 2 * R * n_pad
         if words * itemsize <= _TILED_VMEM_BUDGET_BYTES:
             return SolvePlan("tiled", n_pad, R, words * itemsize)
     return None
@@ -341,25 +347,9 @@ def _csr_gram_batched_jit(values, local_cols, seg_ids, *, n_rows: int,
         return ref.csr_gram_batched_ref(
             values, local_cols, seg_ids, n_rows, n_hat
         )
-    C, E = values.shape
-    if batched_gram_fits(n_hat, n_rows, E):
-        return csr_gram_batched_pallas(
-            values, local_cols, seg_ids, n_rows, n_hat,
-            interpret=not _on_tpu(),
-        )
-    # Resident-G state too big: fall back to the tiled single-chunk kernel,
-    # one launch per chunk (the pre-megabatch economics, correct at any
-    # n_hat <= max_reduced).
-    G = csr_gram_pallas(
-        values[0], local_cols[0], seg_ids[0], n_rows, n_hat,
-        interpret=not _on_tpu(),
+    return csr_gram_megabatch_pallas(
+        values, local_cols, seg_ids, n_rows, n_hat, interpret=not _on_tpu(),
     )
-    for c in range(1, C):
-        G = G + csr_gram_pallas(
-            values[c], local_cols[c], seg_ids[c], n_rows, n_hat,
-            interpret=not _on_tpu(),
-        )
-    return G
 
 
 def csr_gram_batched(values, local_cols, seg_ids, *, n_rows: int,
@@ -460,15 +450,7 @@ def _sharded_batched_solve(devices: int, use_pallas: bool, kscheme: str,
 
     # The solve body is a while loop, which shard_map's replication checker
     # cannot analyse — each device's slice is independent, so the check is
-    # vacuously satisfied and safely disabled (kwarg name changed when
-    # shard_map graduated from jax.experimental).
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-        no_check = {"check_rep": False}
-    else:
-        no_check = {"check_vma": False}
-
+    # vacuously satisfied and safely disabled.
     mesh = make_data_mesh(devices)
     from jax.sharding import PartitionSpec as P
 
@@ -487,11 +469,11 @@ def _sharded_batched_solve(devices: int, use_pallas: bool, kscheme: str,
 
     b = P("data")
     m = P("data", None, None)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         device_solve, mesh=mesh,
         in_specs=(m, b, b, m, P(), b),
         out_specs=(m, b, b, P("data", None)),
-        **no_check,
+        check_vma=False,
     ))
 
 
@@ -594,15 +576,8 @@ def sparse_project(X, support_idx, values, *, impl: str = "auto",
         if impl == "ref" or (impl == "auto" and not _on_tpu()):
             return ref.sparse_project_ref(X, support_idx, values)
         k, cap = support_idx.shape
-        B, n = X.shape
-        # Batch-transpose + zero pad row: column gather becomes row gather.
-        XT = jnp.concatenate(
-            [X.T.astype(jnp.float32), jnp.zeros((1, B), jnp.float32)], axis=0
+        return sparse_project_pallas(
+            X, support_idx.reshape(-1).astype(jnp.int32),
+            jnp.repeat(jnp.arange(k, dtype=jnp.int32), cap),
+            values.reshape(-1), k, block_b=block_b, interpret=not _on_tpu(),
         )
-        idx = jnp.where(values.reshape(-1) != 0, support_idx.reshape(-1), n)
-        cid = jnp.repeat(jnp.arange(k, dtype=jnp.int32), cap)
-        out = sparse_project_pallas(
-            XT, idx.astype(jnp.int32), cid, values.reshape(-1), k, cap,
-            block_b=block_b, interpret=not _on_tpu(),
-        )
-        return out.T

@@ -1,5 +1,8 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if __name__ == "__main__":
+    # 512 forced host devices for the production meshes; set before jax
+    # starts, and only when run as a program (importers keep theirs).
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -11,15 +11,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; older versions default to
-    Auto semantics anyway, so omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -35,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     import numpy as np
 
     dev_array = np.asarray(devices).reshape(shape)
-    return jax.sharding.Mesh(dev_array, axes, **_axis_type_kwargs(len(axes)))
+    return jax.sharding.Mesh(dev_array, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_dev_mesh(shape=(2, 2), axes=("data", "model")):
@@ -45,7 +36,7 @@ def make_dev_mesh(shape=(2, 2), axes=("data", "model")):
     n = int(np.prod(shape))
     devices = jax.devices()[:n]
     dev_array = np.asarray(devices).reshape(shape)
-    return jax.sharding.Mesh(dev_array, axes, **_axis_type_kwargs(len(axes)))
+    return jax.sharding.Mesh(dev_array, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_data_mesh(n_devices: int = 0, *, axis: str = "data"):
@@ -69,4 +60,5 @@ def make_data_mesh(n_devices: int = 0, *, axis: str = "data"):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n}"
         )
     dev_array = np.asarray(devices[:n])
-    return jax.sharding.Mesh(dev_array, (axis,), **_axis_type_kwargs(1))
+    return jax.sharding.Mesh(dev_array, (axis,),
+                             axis_types=(jax.sharding.AxisType.Auto,))

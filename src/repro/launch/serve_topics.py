@@ -31,6 +31,7 @@ import numpy as np
 from repro.core import SPCAConfig, search_lambda
 from repro.core.elimination import Screen
 from repro.data.corpus import NYTIMES_TOPICS, make_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import metrics, trace
 from repro.serve import BatcherConfig, DriftMonitor, MicroBatcher, ModelRegistry
 
@@ -135,7 +136,9 @@ def serve_stream(batcher, docs, *, inflight: int = 256):
     return served, np.bincount(topics, minlength=batcher.projector.pack.k)
 
 
-def main():
+def main(argv=None):
+    """Run fit -> register -> serve -> drift; returns what `_run` returns."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         epilog=_EXAMPLES,
         formatter_class=argparse.RawDescriptionHelpFormatter,
@@ -165,7 +168,7 @@ def main():
                     metavar="S",
                     help="seconds between exporter samples (with "
                          "--export-port)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.smoke:
         args.docs = min(args.docs, 3000)
         args.words = min(args.words, 2500)
@@ -185,6 +188,7 @@ def main():
             extra={"run": "serve_topics"},
         )
 
+    prev_tracer = trace.active()
     tracer = trace.install(trace.Tracer()) if args.trace else None
     try:
         if exporter is not None:
@@ -192,11 +196,11 @@ def main():
             print(f"telemetry: http://127.0.0.1:{exporter.port}"
                   "/{metrics,healthz,varz,tracez} "
                   f"(sampling every {args.export_interval:g}s)")
-        _run(args, exporter)
+        out = _run(args, exporter)
     finally:
         if exporter is not None:
             exporter.stop()
-        trace.install(None)
+        trace.install(prev_tracer)
     if tracer is not None:
         tracer.dump_chrome_trace(args.trace)
         print(f"trace: {args.trace} (load at ui.perfetto.dev)")
@@ -210,9 +214,14 @@ def main():
                 args.metrics, extra={"run": "serve_topics"}
             )
         print(f"metrics: {args.metrics}")
+    return out
 
 
 def _run(args, exporter=None):
+    """Returns a dict: ``results`` (fitted PCs), ``model`` (the registered
+    version, projector included), ``queries`` (the served corpus),
+    ``served``, ``stats`` (batcher latency snapshot) and the two drift
+    reports ``drift`` / ``drift_shifted``."""
     # 1. fit ---------------------------------------------------------------
     print(f"corpus: {args.docs} docs x {args.words} words")
     corpus = make_corpus(args.docs, args.words, topics=NYTIMES_TOPICS, seed=0)
@@ -277,6 +286,8 @@ def _run(args, exporter=None):
     if rep.triggered or not rep2.triggered:
         raise SystemExit("drift monitor misbehaved")
     print("ok: certificate quiet in-distribution, refit flag on drift")
+    return dict(results=results, model=mv, queries=queries, served=served,
+                stats=s, drift=rep, drift_shifted=rep2)
 
 
 if __name__ == "__main__":

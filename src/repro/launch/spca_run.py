@@ -43,6 +43,7 @@ import numpy as np
 from repro.configs.spca_experiments import NYTIMES, PUBMED
 from repro.core import SPCAConfig, fit_components
 from repro.data.corpus import NYTIMES_TOPICS, PUBMED_TOPICS, make_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import metrics, profile, trace
 
 _EXAMPLES = """\
@@ -95,7 +96,9 @@ live telemetry examples:
 """
 
 
-def main():
+def main(argv=None):
+    """Run the fit; returns what `_run` returns (results + diagnostics)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         epilog=_EXAMPLES,
         formatter_class=argparse.RawDescriptionHelpFormatter,
@@ -150,7 +153,8 @@ def main():
                          "batched solves across the first D local devices "
                          "(1-D data mesh; off-TPU set XLA_FLAGS="
                          "--xla_force_host_platform_device_count=D before "
-                         "launching)")
+                         "launching); fewer than D local devices is an "
+                         "error")
     ap.add_argument("--trace", default="", metavar="PATH",
                     help="write the host span timeline as Chrome "
                          "trace-event JSON (Perfetto-loadable) and print "
@@ -170,7 +174,7 @@ def main():
                     help="seconds between exporter samples (with "
                          "--export-port; each interval appends one delta "
                          "snapshot to --metrics)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     exporter = None
     if args.export_port is not None:
@@ -186,6 +190,7 @@ def main():
             extra={"run": "spca_run", "corpus": args.corpus},
         )
 
+    prev_tracer = trace.active()
     tracer = trace.install(trace.Tracer()) if args.trace else None
     try:
         if exporter is not None:
@@ -194,11 +199,11 @@ def main():
                   "/{metrics,healthz,varz,tracez} "
                   f"(sampling every {args.export_interval:g}s)")
         with profile.trace_device(args.profile_dir or None):
-            _run(args)
+            out = _run(args)
     finally:
         if exporter is not None:
             exporter.stop()
-        trace.install(None)
+        trace.install(prev_tracer)
     if tracer is not None:
         tracer.dump_chrome_trace(args.trace)
         print(f"trace: {args.trace} (load at ui.perfetto.dev)")
@@ -214,9 +219,15 @@ def main():
                 extra={"run": "spca_run", "corpus": args.corpus},
             )
         print(f"metrics: {args.metrics}")
+    return out
 
 
 def _run(args):
+    """The fit itself.  Returns a dict: ``results`` (PCResults),
+    ``diagnostics`` (the `fit_components` dict), ``ingest`` (pass/launch
+    counters; empty without --streaming), ``corpus``, ``variances`` (the
+    screen the fit used), ``store`` (the CSR store handle or None),
+    ``cfg`` and ``fit_s``."""
     exp = NYTIMES if args.corpus == "nytimes" else PUBMED
     topics = NYTIMES_TOPICS if args.corpus == "nytimes" else PUBMED_TOPICS
     n_words = args.words or exp.n_words
@@ -233,11 +244,11 @@ def _run(args):
 
         avail = jax.local_device_count()
         if avail < devices:
-            print(f"  --devices {devices} requested but only {avail} local "
-                  f"device(s) exist — falling back to {avail} (set "
-                  "XLA_FLAGS=--xla_force_host_platform_device_count="
-                  f"{devices} before launching to force the topology)")
-            devices = avail
+            raise SystemExit(
+                f"--devices {devices} requested but only {avail} local "
+                "device(s) exist (off-TPU set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={devices} before "
+                "launching)")
 
     cfg = SPCAConfig(max_sweeps=8, lam_search_evals=8,
                      chunk_nnz=args.chunk_nnz, chunk_rows=args.chunk_rows,
@@ -252,6 +263,7 @@ def _run(args):
                      solve_deadline_s=args.solve_deadline_s)
 
     ingest: dict = {}
+    store = None
     if args.streaming:
         from repro.sparse import write_corpus
         from repro.sparse.engine import sparse_stats
@@ -349,6 +361,9 @@ def _run(args):
                       "read error(s)")
     if extras:
         print("reliability: " + "; ".join(extras))
+    return dict(results=results, diagnostics=diag, ingest=ingest,
+                corpus=corpus, variances=np.asarray(var), store=store,
+                cfg=cfg, fit_s=fit_s)
 
 
 if __name__ == "__main__":

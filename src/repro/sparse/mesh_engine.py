@@ -58,13 +58,13 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distributed import _shard_map, psum_partials
+from repro.core.distributed import psum_partials
 from repro.core.elimination import Screen, combine_screens
 from repro.data.bow import local_support_cols
 from repro.data.pipeline import prefetch
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref
-from repro.kernels.csr_gram import csr_gram_batched_pallas
+from repro.kernels.csr_gram import csr_gram_megabatch_pallas
 from repro.kernels.csr_stats import csr_column_stats_pallas
 from repro.launch.mesh import make_data_mesh
 from repro.obs import metrics, trace
@@ -211,9 +211,12 @@ def _stats_step(devices: int, n: int, use_pallas: bool):
 
     acc = P("data", None)
     ent = P("data", None, None)
-    return jax.jit(_shard_map(
+    # check_vma=False: a pallas_call's outputs carry no varying-axes
+    # annotation, and every output here is per-device (P("data", ...)).
+    return jax.jit(jax.shard_map(
         device_fold, mesh=mesh,
         in_specs=(acc,) * 4 + (ent,) * 2, out_specs=(acc,) * 4,
+        check_vma=False,
     ))
 
 
@@ -224,7 +227,7 @@ def _gram_step(devices: int, chunk_rows: int, n_hat: int, use_pallas: bool):
 
     def device_fold(g, err, values, local_cols, seg_ids):
         if use_pallas:
-            pg = csr_gram_batched_pallas(
+            pg = csr_gram_megabatch_pallas(
                 values[0], local_cols[0], seg_ids[0], chunk_rows, n_hat,
                 interpret=interpret,
             )
@@ -236,9 +239,10 @@ def _gram_step(devices: int, chunk_rows: int, n_hat: int, use_pallas: bool):
 
     acc = P("data", None, None)
     ent = P("data", None, None)
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         device_fold, mesh=mesh,
         in_specs=(acc,) * 2 + (ent,) * 3, out_specs=(acc,) * 2,
+        check_vma=False,                # as in `_stats_step`
     ))
 
 
@@ -506,6 +510,8 @@ def _mesh_drain(store: SparseCorpus, acc, *, devices, chunk_nnz, chunk_rows,
                 lane_regs[d].counter("ingest.shard.chunks").inc(
                     sb.lane_chunks[d])
                 lane_regs[d].counter("ingest.shard.nnz").inc(sb.lane_nnz[d])
+                # the registry pools the lanes; the diagnostics keep each
+                _count(counters, f"shard_chunks.{d}", sb.lane_chunks[d])
             _stream_prefetch_stats(pstats, pprev)
             prev_done, done = done, done + sb.lanes
             if (checkpointer is not None

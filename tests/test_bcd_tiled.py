@@ -64,7 +64,11 @@ def test_plan_batched_is_more_conservative():
     batched = ops.plan_fused_solve(768, batch=8)
     assert single.scheme == "resident"
     assert batched is None or batched.scheme == "tiled"
-    assert ops.plan_fused_solve(1664, batch=8) is None
+    # The tiled scheme's X is one VMEM scratch that DMAs itself in and out,
+    # so nothing revolves under the batch grid: the v5e compiler reports the
+    # same scoped VMEM at B=8 as at B=1 (n_pad 1664: 16.3 MiB).
+    assert ops.plan_fused_solve(1664, batch=8) == ops.plan_fused_solve(1664)
+    assert ops.plan_fused_solve(2048, batch=8) is None
 
 
 def test_auto_resolves_to_jnp_off_tpu():
@@ -110,7 +114,7 @@ def test_tiled_parity_above_resident_cap():
 
     n = 772
     assert ops.plan_fused_solve(n).scheme == "tiled"
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(99)
         F = rng.normal(size=(n + 12, n))
         Sigma = jnp.asarray((F.T @ F) / (n + 12), jnp.float64)
@@ -204,7 +208,7 @@ def test_ops_batched_matches_sequential_solves():
     noise and legitimately walk to a different nearby iterate."""
     import jax
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sizes = [9, 33, 60, 41]
         npad = 64
         Sl, X0l, lams, betas = [], [], [], []

@@ -131,14 +131,9 @@ def test_compressed_pmean_error_feedback():
     def f(gs, res):
         return compressed_pmean(gs, res, "data")
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:   # pre-graduation jax: experimental name + kwarg
-        from jax.experimental.shard_map import shard_map
-        no_check = {"check_rep": False}
-    else:
-        no_check = {"check_vma": False}
-    sm = shard_map(f, mesh=mesh, in_specs=(P("data", None), P("data", None)),
-                   out_specs=(P(None), P("data", None)), **no_check)
+    sm = jax.shard_map(f, mesh=mesh,
+                       in_specs=(P("data", None), P("data", None)),
+                       out_specs=(P(None), P("data", None)), check_vma=False)
     res = jnp.zeros((8, 1024), jnp.float32)
     exact = np.asarray(g).mean(0)
     # single step: quantisation error bounded

@@ -1,4 +1,5 @@
 """End-to-end driver: lambda search, deflation, topic recovery."""
+import os
 from dataclasses import replace
 
 import numpy as np
@@ -117,3 +118,38 @@ def test_solve_at_lambda_explained_variance_reasonable():
         v = np.zeros(X.shape[1]); v[t] = 1.0 / np.sqrt(len(t))
         best = max(best, v @ Sigma @ v)
     assert r.variance >= 0.8 * best
+
+
+def test_launcher_refuses_more_devices_than_exist(monkeypatch):
+    """`spca_run --devices D` on a host with fewer than D devices raises
+    instead of running on the devices it finds."""
+    from repro.launch import spca_run
+
+    # a set variable makes the compile-cache helper leave jax's config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "unused")
+    with pytest.raises(SystemExit, match="--devices 64 requested"):
+        spca_run.main(["--docs", "100", "--words", "2500", "--devices", "64"])
+
+
+def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs) == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        d = compile_cache.enable_compile_cache()
+        assert d == compile_cache.DEFAULT_DIR
+        assert os.path.basename(d) == ".jax_cache"
+        assert os.path.isdir(os.path.join(os.path.dirname(d), "src", "repro"))
+        assert jax.config.jax_compilation_cache_dir == d
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          before[1])
