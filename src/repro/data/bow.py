@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core.elimination import Screen, select_support
 from repro.kernels import ops
+from repro.obs import trace
 
 
 def local_support_cols(support: np.ndarray, col_ids: np.ndarray) -> np.ndarray:
@@ -72,7 +73,8 @@ class StreamingAccumulator:
 
     def update_csr_batch(self, mb) -> "StreamingAccumulator":
         """Fold in a `repro.sparse.store.CSRMegaBatch` of C chunks with a
-        single kernel dispatch."""
+        single kernel dispatch; the feed's spans carry its ``index`` as
+        ``b``."""
         raise NotImplementedError
 
     def merge(self, other: "StreamingAccumulator") -> "StreamingAccumulator":
@@ -140,12 +142,17 @@ class StreamingStats(StreamingAccumulator):
         return self
 
     def update_csr_batch(self, mb) -> "StreamingStats":
-        """C chunks -> ONE kernel dispatch (and one host f64 fold)."""
+        """C chunks -> ONE kernel dispatch (and one host f64 fold).  The
+        fold is an ``ingest.readback`` span, opened after the kernel has
+        finished (while tracing), so it holds only the copy and the add."""
         s, ss = ops.csr_column_stats(
             mb.values, mb.col_ids, n=self.n, impl=self.impl, nnz=mb.nnz,
+            b=mb.index,
         )
-        self.sum += np.asarray(s, np.float64)
-        self.sumsq += np.asarray(ss, np.float64)
+        trace.device_sync((s, ss))
+        with trace.span("ingest.readback", b=mb.index):
+            self.sum += np.asarray(s, np.float64)
+            self.sumsq += np.asarray(ss, np.float64)
         self.count += int(np.sum(mb.n_rows))
         return self
 
@@ -256,15 +263,18 @@ class StreamingGram(StreamingAccumulator):
         return self
 
     def update_csr_batch(self, mb) -> "StreamingGram":
-        """C chunks -> ONE kernel dispatch, accumulated on device."""
+        """C chunks -> ONE kernel dispatch, accumulated on device.  The
+        support-column remap is an ``ingest.prep`` span."""
         self._check_rows(int(np.max(mb.n_rows, initial=0)))
         if self.support.size == 0:
             self.count += int(np.sum(mb.n_rows))
             return self
+        with trace.span("ingest.prep", b=mb.index):
+            local = self._local_cols(mb.col_ids)
         self._acc(ops.csr_gram_batched(
-            mb.values, self._local_cols(mb.col_ids), mb.seg_ids,
+            mb.values, local, mb.seg_ids,
             n_rows=self.chunk_rows, n_hat=self.support.size, impl=self.impl,
-            nnz=mb.nnz,
+            nnz=mb.nnz, b=mb.index,
         ))
         self.count += int(np.sum(mb.n_rows))
         return self

@@ -386,6 +386,7 @@ def _launch(
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=TILED_VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name="bcd_fused_tiled",
         )(scal.reshape(-1), n_valids, Sigma3, X03)
     elif scheme == "resident":
         X, hist, meta = pl.pallas_call(
@@ -399,6 +400,7 @@ def _launch(
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name="bcd_fused_resident",
         )(scal.reshape(-1), n_valids, Sigma3, X03)
     else:
         raise ValueError(f"unknown scheme {scheme!r}")

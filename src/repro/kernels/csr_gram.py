@@ -200,6 +200,7 @@ def csr_gram_batched_pallas(
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
+        name="csr_gram_megabatch",
         cost_estimate=pl.CostEstimate(
             flops=C * (2 * R * n_pad * n_pad + 2 * R * n_pad * rows * 128),
             bytes_accessed=(3 * C * rows * 128 + n_pad * n_pad) * 4,
@@ -275,6 +276,7 @@ def csr_gram_pallas(
         out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n_tiles, R, 128), jnp.float32)],
         interpret=interpret,
+        name="csr_gram_chunk",
         cost_estimate=pl.CostEstimate(
             flops=2 * R * n_pad * n_pad + 2 * R * n_pad * rows * 128,
             bytes_accessed=(3 * rows * 128 + n_pad * n_pad) * 4,

@@ -135,6 +135,7 @@ def csr_column_stats_pallas(
         ],
         out_shape=out_shape,
         interpret=interpret,
+        name="csr_column_stats",
         cost_estimate=pl.CostEstimate(
             # one (2S, 128) x (128, 128) MXU contraction per 128 entries
             flops=2 * 2 * S * 128 * Ep // 128,

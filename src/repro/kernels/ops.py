@@ -24,7 +24,7 @@ import functools
 
 from dataclasses import dataclass
 
-from repro.obs import metrics, profile
+from repro.obs import metrics, trace
 
 from . import ref
 from .bcd_fused import bcd_solve_batched_pallas, bcd_solve_pallas
@@ -64,15 +64,12 @@ def solver_fault_after(site: str, out, *, max_sweeps: int):
     return out
 
 
-def _launch(op: str):
+def _launch(op: str) -> None:
     """Per-op dispatch accounting at the wrapper boundary: bump the
-    ``kernel.launches.<op>`` registry counter and open an ``ops.<op>``
-    profiler region (`obs.profile.annotate` — a free no-op unless device
-    profiling was enabled, so the untraced hot path pays one counter
-    increment).  Counted here, not inside jit: the wrappers run eagerly
-    per call, so counts are dispatches, not traces."""
+    ``kernel.launches.<op>`` registry counter.  Counted here, not inside
+    jit: the wrappers run eagerly per call, so counts are dispatches, not
+    traces."""
     metrics.counter(f"kernel.launches.{op}").inc()
-    return profile.annotate(f"ops.{op}")
 
 
 # VMEM budgets for the two fused-solve execution schemes, against the scoped
@@ -155,12 +152,12 @@ _bcd_solve_batched_ref_jit = jax.jit(
 
 def column_stats(A, *, impl: str = "auto", block_m: int = 256, block_n: int = 512):
     """(col_sum, col_sumsq) in f32 — feeds the Thm 2.1 variance screen."""
-    with _launch("column_stats"):
-        if impl == "ref" or (impl == "auto" and not _on_tpu()):
-            return ref.column_stats_ref(A)
-        return column_stats_pallas(
-            A, block_m=block_m, block_n=block_n, interpret=not _on_tpu()
-        )
+    _launch("column_stats")
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+        return ref.column_stats_ref(A)
+    return column_stats_pallas(
+        A, block_m=block_m, block_n=block_n, interpret=not _on_tpu()
+    )
 
 
 def column_variances(A, *, impl: str = "auto"):
@@ -175,13 +172,13 @@ def column_variances(A, *, impl: str = "auto"):
 def gram(A, *, impl: str = "auto", block_i: int = 128, block_j: int = 128,
          block_k: int = 512):
     """A^T A in f32 — the reduced covariance numerator."""
-    with _launch("gram"):
-        if impl == "ref" or (impl == "auto" and not _on_tpu()):
-            return ref.gram_ref(A)
-        return gram_pallas(
-            A, block_i=block_i, block_j=block_j, block_k=block_k,
-            interpret=not _on_tpu(),
-        )
+    _launch("gram")
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+        return ref.gram_ref(A)
+    return gram_pallas(
+        A, block_i=block_i, block_j=block_j, block_k=block_k,
+        interpret=not _on_tpu(),
+    )
 
 
 try:                                     # scipy ships with jax; the spgemm
@@ -240,17 +237,19 @@ def _csr_gram_host(values, local_cols, seg_ids, n_rows: int, n_hat: int):
     return Bd.T @ Bd
 
 
-def _sync_host_inputs(*arrays):
+def _sync_host_inputs(*arrays, b=None):
     """Convert concrete host arrays bound for a jit path into device
     buffers, BLOCKING until the copies land.  Callers like the megabatch
     ring reuse their host buffers as soon as the wrapper returns; async
     dispatch makes no promise about when a raw numpy argument is read,
     and ``jnp.asarray`` may alias host memory on CPU — hence the
-    explicit ``copy=True`` plus the block."""
+    explicit ``copy=True`` plus the block.  The copy is an
+    ``ingest.h2d`` span carrying the megabatch index ``b``."""
     if not any(isinstance(a, np.ndarray) for a in arrays):
         return arrays
-    out = tuple(jnp.array(a, copy=True) for a in arrays)
-    jax.block_until_ready(out)
+    with trace.span("ingest.h2d", b=b):
+        out = tuple(jnp.array(a, copy=True) for a in arrays)
+        jax.block_until_ready(out)
     return out
 
 
@@ -287,21 +286,23 @@ def _csr_column_stats_jit(values, col_ids, *, n: int, impl: str,
 
 
 def csr_column_stats(values, col_ids, *, n: int, impl: str = "auto",
-                     block_e: int = 4096, nnz=None):
+                     block_e: int = 4096, nnz=None, b=None):
     """(col_sum, col_sumsq) in f32 from CSR entries — the sparse leg of the
     Thm 2.1 screen.  ``values``/``col_ids`` are flat ``(E,)`` for one chunk
     or ``(C, E)`` for a megabatch of C chunks reduced in ONE dispatch (one
     `pallas_call` on TPU, one XLA scatter off it).  Chunks from the store
     have a fixed shape, so this traces once per (C, chunk_nnz, n) and
     never recompiles.  ``nnz`` (scalar or (C,)), when given with concrete
-    host arrays, asserts the ``value 0`` padding contract."""
-    _assert_csr_padding(values, nnz)
-    with _launch("csr_column_stats"):
-        if _host_path(impl, values, col_ids):
-            return _csr_column_stats_host(values, col_ids, n)
-        values, col_ids = _sync_host_inputs(values, col_ids)
-        return _csr_column_stats_jit(values, col_ids, n=n, impl=impl,
-                                     block_e=block_e)
+    host arrays, asserts the ``value 0`` padding contract.  ``b`` is the
+    megabatch index the ``ingest.prep`` / ``ingest.h2d`` spans carry."""
+    with trace.span("ingest.prep", b=b):
+        _assert_csr_padding(values, nnz)
+    _launch("csr_column_stats")
+    if _host_path(impl, values, col_ids):
+        return _csr_column_stats_host(values, col_ids, n)
+    values, col_ids = _sync_host_inputs(values, col_ids, b=b)
+    return _csr_column_stats_jit(values, col_ids, n=n, impl=impl,
+                                 block_e=block_e)
 
 
 # back-compat: tests introspect the jit cache through the public name
@@ -328,14 +329,14 @@ def csr_gram(values, local_cols, seg_ids, *, n_rows: int, n_hat: int,
     (entry not on the support); ``seg_ids`` are chunk-local rows.  Fixed
     chunk shapes keep this a single trace per (chunk_nnz, n_hat)."""
     _assert_csr_padding(values, nnz)
-    with _launch("csr_gram"):
-        if _host_path(impl, values, local_cols, seg_ids):
-            return _csr_gram_host(values, local_cols, seg_ids, n_rows, n_hat)
-        values, local_cols, seg_ids = _sync_host_inputs(
-            values, local_cols, seg_ids
-        )
-        return _csr_gram_jit(values, local_cols, seg_ids, n_rows=n_rows,
-                             n_hat=n_hat, impl=impl)
+    _launch("csr_gram")
+    if _host_path(impl, values, local_cols, seg_ids):
+        return _csr_gram_host(values, local_cols, seg_ids, n_rows, n_hat)
+    values, local_cols, seg_ids = _sync_host_inputs(
+        values, local_cols, seg_ids
+    )
+    return _csr_gram_jit(values, local_cols, seg_ids, n_rows=n_rows,
+                         n_hat=n_hat, impl=impl)
 
 
 @functools.partial(
@@ -353,21 +354,23 @@ def _csr_gram_batched_jit(values, local_cols, seg_ids, *, n_rows: int,
 
 
 def csr_gram_batched(values, local_cols, seg_ids, *, n_rows: int,
-                     n_hat: int, impl: str = "auto", nnz=None):
+                     n_hat: int, impl: str = "auto", nnz=None, b=None):
     """Megabatch gather-Gram: C chunks' ``sum_c B_c^T B_c`` in ONE dispatch
     (grid=(C,) `pallas_call` with the Gram accumulator VMEM-resident across
     the batch on TPU, one stacked spgemm off it).  Inputs are (C, E);
     ``nnz`` (C,), when given with concrete host arrays, asserts the
-    ``value 0`` padding contract."""
-    _assert_csr_padding(values, nnz)
-    with _launch("csr_gram_batched"):
-        if _host_path(impl, values, local_cols, seg_ids):
-            return _csr_gram_host(values, local_cols, seg_ids, n_rows, n_hat)
-        values, local_cols, seg_ids = _sync_host_inputs(
-            values, local_cols, seg_ids
-        )
-        return _csr_gram_batched_jit(values, local_cols, seg_ids,
-                                     n_rows=n_rows, n_hat=n_hat, impl=impl)
+    ``value 0`` padding contract; ``b`` is the megabatch index the
+    ``ingest.prep`` / ``ingest.h2d`` spans carry."""
+    with trace.span("ingest.prep", b=b):
+        _assert_csr_padding(values, nnz)
+    _launch("csr_gram_batched")
+    if _host_path(impl, values, local_cols, seg_ids):
+        return _csr_gram_host(values, local_cols, seg_ids, n_rows, n_hat)
+    values, local_cols, seg_ids = _sync_host_inputs(
+        values, local_cols, seg_ids, b=b
+    )
+    return _csr_gram_batched_jit(values, local_cols, seg_ids,
+                                 n_rows=n_rows, n_hat=n_hat, impl=impl)
 
 
 def _resolve_scheme(scheme: str, n: int, itemsize: int, batch: int):
@@ -412,29 +415,29 @@ def bcd_solve(Sigma, lam, beta, X0=None, *, max_sweeps: int = 20,
     use_pallas = (impl == "pallas" or (
         impl == "auto" and _on_tpu() and Sigma.dtype.itemsize <= 4
     )) and resolved is not None
-    with _launch("bcd_solve"):
-        solver_fault_before("bcd_solve")
-        if not use_pallas:
-            if n_valid is None:
-                out = _bcd_solve_ref_jit(
-                    Sigma, lam, beta, X0, tol,
-                    max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
-                    tau_iters=tau_iters,
-                )
-            else:
-                out = _bcd_solve_masked_ref_jit(
-                    Sigma, lam, beta, X0, tol, n_valid,
-                    max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
-                    tau_iters=tau_iters,
-                )
-        else:
-            kscheme, kpanel = resolved
-            out = bcd_solve_pallas(
+    _launch("bcd_solve")
+    solver_fault_before("bcd_solve")
+    if not use_pallas:
+        if n_valid is None:
+            out = _bcd_solve_ref_jit(
                 Sigma, lam, beta, X0, tol,
                 max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
-                tau_iters=tau_iters, n_valid=n_valid, scheme=kscheme,
-                panel_rows=panel_rows or kpanel, interpret=not _on_tpu(),
+                tau_iters=tau_iters,
             )
+        else:
+            out = _bcd_solve_masked_ref_jit(
+                Sigma, lam, beta, X0, tol, n_valid,
+                max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+                tau_iters=tau_iters,
+            )
+    else:
+        kscheme, kpanel = resolved
+        out = bcd_solve_pallas(
+            Sigma, lam, beta, X0, tol,
+            max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+            tau_iters=tau_iters, n_valid=n_valid, scheme=kscheme,
+            panel_rows=panel_rows or kpanel, interpret=not _on_tpu(),
+        )
     return solver_fault_after("bcd_solve", out, max_sweeps=max_sweeps)
 
 
@@ -529,34 +532,34 @@ def bcd_solve_batched(Sigmas, lams, betas, X0s, n_valids, *,
                 [X0s, jnp.broadcast_to(X0s[:1], (pad, n, n))])
             n_valids = jnp.concatenate(
                 [n_valids, jnp.broadcast_to(n_valids[:1], (pad,))])
-        with _launch("bcd_solve_batched"):
-            solver_fault_before("bcd_solve_batched")
-            fn = _sharded_batched_solve(
-                D, use_pallas, kscheme, kpanel,
-                max_sweeps, qp_sweeps, tau_iters, panel_rows,
-            )
-            X, obj, sweeps, hist = fn(Sigmas, lams, betas, X0s, tol,
-                                      n_valids)
+        _launch("bcd_solve_batched")
+        solver_fault_before("bcd_solve_batched")
+        fn = _sharded_batched_solve(
+            D, use_pallas, kscheme, kpanel,
+            max_sweeps, qp_sweeps, tau_iters, panel_rows,
+        )
+        X, obj, sweeps, hist = fn(Sigmas, lams, betas, X0s, tol,
+                                  n_valids)
         return solver_fault_after(
             "bcd_solve_batched", (X[:B], obj[:B], sweeps[:B], hist[:B]),
             max_sweeps=max_sweeps,
         )
-    with _launch("bcd_solve_batched"):
-        solver_fault_before("bcd_solve_batched")
-        if not use_pallas:
-            out = _bcd_solve_batched_ref_jit(
-                Sigmas, lams, betas, X0s, tol, n_valids,
-                max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
-                tau_iters=tau_iters,
-            )
-        else:
-            kscheme, kpanel = resolved
-            out = bcd_solve_batched_pallas(
-                Sigmas, lams, betas, X0s, tol, n_valids,
-                max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
-                tau_iters=tau_iters, scheme=kscheme,
-                panel_rows=panel_rows or kpanel, interpret=not _on_tpu(),
-            )
+    _launch("bcd_solve_batched")
+    solver_fault_before("bcd_solve_batched")
+    if not use_pallas:
+        out = _bcd_solve_batched_ref_jit(
+            Sigmas, lams, betas, X0s, tol, n_valids,
+            max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+            tau_iters=tau_iters,
+        )
+    else:
+        kscheme, kpanel = resolved
+        out = bcd_solve_batched_pallas(
+            Sigmas, lams, betas, X0s, tol, n_valids,
+            max_sweeps=max_sweeps, qp_sweeps=qp_sweeps,
+            tau_iters=tau_iters, scheme=kscheme,
+            panel_rows=panel_rows or kpanel, interpret=not _on_tpu(),
+        )
     return solver_fault_after("bcd_solve_batched", out,
                               max_sweeps=max_sweeps)
 
@@ -572,12 +575,12 @@ def sparse_project(X, support_idx, values, *, impl: str = "auto",
                    block_b: int = 512):
     """(B, k) document->topic scores through the gather representation —
     the serving hot path (see ``repro.serve.projector``)."""
-    with _launch("sparse_project"):
-        if impl == "ref" or (impl == "auto" and not _on_tpu()):
-            return ref.sparse_project_ref(X, support_idx, values)
-        k, cap = support_idx.shape
-        return sparse_project_pallas(
-            X, support_idx.reshape(-1).astype(jnp.int32),
-            jnp.repeat(jnp.arange(k, dtype=jnp.int32), cap),
-            values.reshape(-1), k, block_b=block_b, interpret=not _on_tpu(),
-        )
+    _launch("sparse_project")
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+        return ref.sparse_project_ref(X, support_idx, values)
+    k, cap = support_idx.shape
+    return sparse_project_pallas(
+        X, support_idx.reshape(-1).astype(jnp.int32),
+        jnp.repeat(jnp.arange(k, dtype=jnp.int32), cap),
+        values.reshape(-1), k, block_b=block_b, interpret=not _on_tpu(),
+    )

@@ -94,6 +94,7 @@ def sparse_project_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bp, 128), jnp.float32),
         interpret=interpret,
+        name="sparse_project",
         cost_estimate=pl.CostEstimate(
             flops=2 * Bp * P * 128,
             bytes_accessed=(Bp * P * 128 + P * 3 + Bp * 128) * 4,

@@ -77,7 +77,8 @@ observability examples:
   #   m.jsonl   -> one JSON line: solver.*, cov.*, search.*, ingest.*
   #                (incl. ingest.prefetch.* stall time), kernel.launches.*
 
-  # device-level jax.profiler trace with annotated kernel dispatch sites
+  # device-level jax.profiler trace; the program spans land on its host
+  # planes beside the device ops
   python -m repro.launch.spca_run --profile-dir /tmp/jaxtrace
 
 live telemetry examples:
@@ -163,8 +164,8 @@ def main(argv=None):
                     help="append one metrics-registry snapshot (JSON line) "
                          "after the fit")
     ap.add_argument("--profile-dir", default="", metavar="DIR",
-                    help="run a jax.profiler device trace into DIR with "
-                         "the kernel dispatch sites annotated")
+                    help="run a jax.profiler device trace into DIR, with "
+                         "the program spans on its host planes")
     ap.add_argument("--export-port", type=int, default=None, metavar="PORT",
                     help="start the background telemetry exporter and serve "
                          "/metrics /healthz /varz /tracez on this port "

@@ -3,7 +3,8 @@
 Five pieces, all zero-required-dependency and inert by default:
 
   obs.trace    — nestable context-manager spans with monotonic wall time
-                 and optional device-sync boundaries; Chrome trace-event
+                 and optional device-sync boundaries, each also a
+                 `jax.profiler` annotation while tracing; Chrome trace-event
                  JSON (Perfetto) + human tree export + a bounded ring of
                  recently completed spans for live inspection.
   obs.metrics  — typed Counter/Gauge/Histogram registry with JSONL
@@ -16,8 +17,10 @@ Five pieces, all zero-required-dependency and inert by default:
                  registry with delta-aware timestamped records (JSONL
                  time series) and serving /metrics (Prometheus text),
                  /healthz, /varz, /tracez over stdlib HTTP.
-  obs.profile  — `jax.profiler` TraceAnnotation/named_scope wrappers
-                 around kernel dispatch sites, behind a no-op default.
+  obs.profile  — `trace_device`: a `jax.profiler` device trace over a
+                 block, with a tracer installed so the program spans
+                 (which open a TraceAnnotation each while tracing) land
+                 on its host planes, on the device trace's clock.
 
 Span/metric naming scheme and the diagnostics-dict compatibility
 contract: see ROADMAP.md "Observability".

@@ -1,83 +1,36 @@
-"""Profiler hooks: line host spans up with device traces.
+"""Device profiles with the program spans on them.
 
-`jax.profiler` traces show kernel launches by XLA-mangled names;
-annotating the dispatch sites in `kernels.ops` with
-`jax.profiler.TraceAnnotation` (host-side region) and
-`jax.profiler.named_scope` (trace-time region, shows up inside the
-compiled program's events) makes the device trace legible next to the
-`obs.trace` host spans — the screen/Gram megabatch span and its
-`pallas_call` line up by name.
-
-Everything here is a NO-OP until `enable()` is called (or a device trace
-is started through `trace_device`): the dispatch wrappers are on hot
-paths and must cost one module-global check when profiling is off.
-``jax`` is imported lazily so the module stays importable (and inert)
-anywhere the stdlib is.
-
-Note on jit caching: `named_scope` is a trace-time construct, so scopes
-only appear in programs traced AFTER `enable()` — enable profiling before
-the first call of the op you want annotated (fresh process or fresh
-shapes), as `launch.spca_run --profile-dir` does.
+`trace_device` runs a `jax.profiler` trace over a block.  The program's
+own `obs.trace` spans open a `jax.profiler.TraceAnnotation` of the same
+name while a tracer is installed, so they land on the profile's host
+planes beside the device ops; `trace_device` installs a tracer for the
+block when none is, so a profile always carries them.  ``jax`` is
+imported lazily, and a ``None`` directory is a no-op.
 """
 from __future__ import annotations
 
 import contextlib
 
-_enabled = False
-
-
-def enable(on: bool = True) -> None:
-    """Turn annotation emission on/off process-wide."""
-    global _enabled
-    _enabled = bool(on)
-
-
-def enabled() -> bool:
-    return _enabled
-
-
-def annotate(name: str, **kwargs):
-    """Host-side profiler region around a dispatch site: a
-    `jax.profiler.TraceAnnotation` when enabled, a free no-op otherwise."""
-    if not _enabled:
-        return contextlib.nullcontext()
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name, **kwargs)
-    except ImportError:  # pragma: no cover - jax ships in the image
-        return contextlib.nullcontext()
-
-
-def named_scope(name: str):
-    """Trace-time scope for code INSIDE a jitted function — names the
-    resulting XLA ops so device trace events match the host span names."""
-    if not _enabled:
-        return contextlib.nullcontext()
-    try:
-        import jax
-
-        return jax.named_scope(name)
-    except ImportError:  # pragma: no cover
-        return contextlib.nullcontext()
+from . import trace
 
 
 @contextlib.contextmanager
 def trace_device(log_dir: str | None):
     """``with profile.trace_device(dir):`` — run a `jax.profiler` device
-    trace over the block (TensorBoard/Perfetto-loadable), enabling the
-    dispatch annotations for its duration.  ``None`` is a no-op, so
-    callers can pass an optional CLI flag straight through."""
+    trace over the block (TensorBoard/Perfetto-loadable).  ``None`` is a
+    no-op, so callers can pass an optional CLI flag straight through."""
     if not log_dir:
         yield
         return
     import jax
 
-    prev = _enabled
-    enable(True)
+    own = trace.active() is None
+    if own:
+        trace.install(trace.Tracer())
     jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-        enable(prev)
+        if own:
+            trace.install(None)

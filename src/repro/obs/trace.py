@@ -3,17 +3,26 @@
 One `Tracer` holds a forest of nestable spans (screen pass -> megabatch
 dispatches, lambda search -> per-eval / batched-round solves, serve
 batches ...), each with monotonic wall time (`time.perf_counter_ns`),
-attached attributes, and an optional *device-sync boundary*: a span that
-ends right after a `jax.block_until_ready` measures completed device work,
-not just async dispatch.
+its parent on its own thread, attached attributes, and an optional
+*device-sync boundary*: a span that ends right after a
+`jax.block_until_ready` measures completed device work, not just async
+dispatch.
+
+One clock with the device: while a tracer is installed, every span also
+opens a `jax.profiler.TraceAnnotation` of the same name (its scalar
+attributes as the annotation's stats).  Inside a running `jax.profiler`
+trace the spans therefore land on the trace's host planes, on the same
+clock as the device ops, so each idle stretch of a device can be put down
+to the innermost program span open at the time.  Outside a device trace
+an annotation costs about a microsecond.
 
 Instrumentation sites call the module-level `span(...)` helper, which is a
 shared no-op singleton until a tracer is installed (`install` /
 `enable()` context manager) — the hot paths pay one global read and a
-``None`` check when tracing is off.  Span stacks are per-thread (the serve
-microbatcher and the ingest prefetcher run worker threads), so spans
-opened on another thread become roots on that thread's own timeline
-rather than corrupting the caller's stack.
+``None`` check when tracing is off, and no annotation is made.  Span
+stacks are per-thread (the serve microbatcher and the ingest prefetcher
+run worker threads), so spans opened on another thread become roots on
+that thread's own timeline rather than corrupting the caller's stack.
 
 Exports:
 
@@ -24,8 +33,8 @@ Exports:
       the span forest as nested dicts / a human-readable tree with
       per-span total and *self* time (total minus the children's totals).
 
-Zero required dependencies: stdlib only; ``jax`` is imported lazily and
-only for the optional sync boundary.
+Zero required dependencies: stdlib only; ``jax`` is imported lazily, for
+the annotations and the optional sync boundary.
 """
 from __future__ import annotations
 
@@ -38,9 +47,12 @@ from collections import deque
 
 
 class Span:
-    """One timed region.  ``t0``/``t1`` are perf_counter_ns ticks."""
+    """One timed region.  ``t0``/``t1`` are perf_counter_ns ticks;
+    ``parent`` is the span it nests under on its own thread (None for a
+    root)."""
 
-    __slots__ = ("name", "attrs", "t0", "t1", "children", "tid", "root")
+    __slots__ = ("name", "attrs", "t0", "t1", "children", "tid", "root",
+                 "parent")
 
     def __init__(self, name: str, attrs: dict, tid: str):
         self.name = name
@@ -50,6 +62,7 @@ class Span:
         self.t1: int | None = None
         self.children: list[Span] = []
         self.root = False
+        self.parent: Span | None = None
 
     # ------------------------------------------------------------- timings
     @property
@@ -63,23 +76,32 @@ class Span:
 
 
 class _SpanCtx:
-    """Context manager binding one span to one tracer; re-entrant safe
-    because each ``span()`` call creates a fresh instance."""
+    """Context manager binding one span to one tracer and its profiler
+    annotation; re-entrant safe because each ``span()`` call creates a
+    fresh instance."""
 
-    __slots__ = ("_tracer", "_span", "_sync")
+    __slots__ = ("_tracer", "_span", "_sync", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span, sync):
         self._tracer = tracer
         self._span = span
         self._sync = sync
+        self._ann = None
 
     def __enter__(self) -> Span:
+        self._ann = _annotation(self._span.name, self._span.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self._span
 
     def __exit__(self, *exc) -> bool:
-        if self._sync is not None:
-            device_sync(self._sync)
-        self._tracer._close(self._span)
+        try:
+            if self._sync is not None:
+                device_sync(self._sync)
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            self._tracer._close(self._span)
         return False
 
 
@@ -142,6 +164,7 @@ class Tracer:
         sp = Span(name, attrs, threading.current_thread().name)
         st = self._stack()
         if st:
+            sp.parent = st[-1]
             st[-1].children.append(sp)
         else:
             sp.root = True
@@ -291,6 +314,30 @@ def _jsonable(v):
     if isinstance(v, (list, tuple)):
         return [_jsonable(x) for x in v]
     return repr(v)
+
+
+_TraceAnnotation = None      # jax.profiler.TraceAnnotation, once loaded
+
+
+def _annotation(name: str, attrs: dict):
+    """A `jax.profiler.TraceAnnotation` named like the span, carrying its
+    scalar attributes as stats (None where jax is missing)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # pragma: no cover - jax ships in the image
+            TraceAnnotation = False
+        _TraceAnnotation = TraceAnnotation
+    if _TraceAnnotation is False:
+        return None
+    stats = {}
+    for k, v in attrs.items():
+        v = _jsonable(v)
+        if isinstance(v, (int, float)) or (isinstance(v, str)
+                                          and not set(v) & set("#,=")):
+            stats[k] = v
+    return _TraceAnnotation(name, **stats)
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,11 @@ most ``max_wait_ms`` after the first), scatters them into a zero-padded
 compute — the same producer/consumer idiom the LM input pipeline uses.
 
 Every request resolves a ``concurrent.futures.Future`` with its (k,) score
-vector; per-request wall latency feeds the p50/p99 report.
+vector; per-request wall latency feeds the p50/p99 report.  Each
+request's wait from ``submit`` until the collector pops it goes into the
+``serve.queue_wait_s`` histogram; building a batch is a ``serve.build``
+span on the collector thread, serving it a ``serve.batch`` span on the
+server thread, and the two share the batch's ``seq``.
 """
 from __future__ import annotations
 
@@ -131,6 +135,7 @@ class MicroBatcher:
         self._q: queue.Queue = queue.Queue()
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
+        self._seq = 0            # batches built, on the collector thread
 
     # ------------------------------------------------------------- client
     def submit(self, word_ids, counts) -> Future:
@@ -179,7 +184,8 @@ class MicroBatcher:
         return True
 
     def _collect(self):
-        """Yield (requests, padded (max_batch, n) matrix) until stopped."""
+        """Yield (requests, padded (max_batch, n) matrix, seq) until
+        stopped."""
         cfg = self.cfg
         while not self._stop.is_set():
             try:
@@ -188,7 +194,9 @@ class MicroBatcher:
                 continue
             if first is None:       # shutdown sentinel
                 return
+            waits = [time.perf_counter() - first.t_submit]
             if self._expired(first):
+                metrics.histogram("serve.queue_wait_s").observe_many(waits)
                 continue
             reqs = [first]
             deadline = time.perf_counter() + cfg.max_wait_ms / 1e3
@@ -202,31 +210,38 @@ class MicroBatcher:
                     break
                 if r is None:
                     break
+                waits.append(time.perf_counter() - r.t_submit)
                 if not self._expired(r):
                     reqs.append(r)
-            X = np.zeros((cfg.max_batch, self.n), np.float32)
-            live = []
-            for r in reqs:
-                try:   # a malformed request fails ITS future, not the loop
-                    w = r.word_ids
-                    if w.size and (int(w.min()) < 0 or int(w.max()) >= self.n):
-                        # negative ids would silently alias into the vocab
-                        # tail via numpy indexing — reject them explicitly
-                        raise IndexError(
-                            f"word ids outside [0, {self.n})")
-                    np.add.at(X[len(live)], w, r.counts)
-                    live.append(r)
-                except (IndexError, ValueError, TypeError) as e:
-                    X[len(live)] = 0.0   # scatter may have partially landed
-                    r.future.set_exception(e)
+            metrics.histogram("serve.queue_wait_s").observe_many(waits)
+            seq = self._seq
+            self._seq += 1
+            with trace.span("serve.build", batch=len(reqs), seq=seq):
+                X = np.zeros((cfg.max_batch, self.n), np.float32)
+                live = []
+                for r in reqs:
+                    try:   # a malformed request fails ITS future, not the loop
+                        w = r.word_ids
+                        if w.size and (int(w.min()) < 0
+                                       or int(w.max()) >= self.n):
+                            # negative ids would silently alias into the
+                            # vocab tail via numpy indexing — reject them
+                            raise IndexError(
+                                f"word ids outside [0, {self.n})")
+                        np.add.at(X[len(live)], w, r.counts)
+                        live.append(r)
+                    except (IndexError, ValueError, TypeError) as e:
+                        X[len(live)] = 0.0   # scatter may have partially landed
+                        r.future.set_exception(e)
             if live:
-                yield live, X
+                yield live, X, seq
 
     def _serve_loop(self):
         # Runs on the server thread: spans opened here land on that
         # thread's own root timeline (see obs.trace thread model).
-        for reqs, X in prefetch(self._collect(), size=self.cfg.prefetch_depth):
-            with trace.span("serve.batch", batch=len(reqs)):
+        for reqs, X, seq in prefetch(self._collect(),
+                                     size=self.cfg.prefetch_depth):
+            with trace.span("serve.batch", batch=len(reqs), seq=seq):
                 try:
                     scores = np.asarray(self.projector.project(X))
                 except Exception as e:      # fail the waiting futures, not us

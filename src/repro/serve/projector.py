@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core.spca import PCResult
 from repro.kernels import ops
+from repro.obs import trace
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -113,8 +114,12 @@ class TopicProjector:
         self._sorted_slots = live[order]         # (nnz,) their flat slots
 
     def project(self, X) -> jax.Array:
-        """(B, n) counts -> (B, k) scores."""
-        return self._project(jnp.asarray(X))
+        """(B, n) counts -> (B, k) scores.  The batch's copy to the device
+        is a ``serve.h2d`` span, which ends on the landed copy while
+        tracing (and on its dispatch otherwise)."""
+        with trace.span("serve.h2d"):
+            X = trace.device_sync(jnp.asarray(X))
+        return self._project(X)
 
     def project_docs(self, docs) -> np.ndarray:
         """Sparse path: ``docs`` is a list of (word_ids, counts) pairs.

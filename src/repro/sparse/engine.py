@@ -92,6 +92,39 @@ def _stream_prefetch_stats(pstats: dict, prev: dict) -> None:
         prev["occupancy_sum"] = pstats.get("occupancy_sum", 0)
 
 
+def _timed_next(it, span, b: int):
+    """``it`` with each ``next`` inside ``span(b)``, ``b`` being the index
+    of the megabatch that comes out next (a superbatch of ``lanes``
+    megabatches moves it on by ``lanes``), so spans on the reader thread
+    and on the pass thread join on ``b``.  Closes ``it`` when done or
+    abandoned."""
+    try:
+        while True:
+            with span(b):
+                item = next(it, None)
+            if item is None:
+                return
+            yield item
+            b = item.index + getattr(item, "lanes", 1)
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
+
+
+def _feed(it, *, start_batch: int, prefetch_depth: int, stats: dict):
+    """The pass thread's view of the store iterator ``it``: each item's
+    read and packing is an ``ingest.read`` span (on the prefetch reader
+    thread when ``prefetch_depth`` > 0), and each wait for the next item
+    an ``ingest.feed_wait`` span on the pass thread."""
+    it = _timed_next(it, lambda b: trace.span("ingest.read", b=b),
+                     start_batch)
+    if prefetch_depth > 0:
+        it = prefetch(it, size=prefetch_depth, stats=stats)
+    return _timed_next(it, lambda b: trace.span("ingest.feed_wait", b=b),
+                       start_batch)
+
+
 def _drain(store: SparseCorpus, acc, *, chunk_nnz, chunk_rows, megabatch,
            prefetch_depth, host_id, num_hosts, counters, launch_key,
            checkpointer: PassCheckpointer | None = None, kind: str = "",
@@ -109,9 +142,12 @@ def _drain(store: SparseCorpus, acc, *, chunk_nnz, chunk_rows, megabatch,
     ``complete=True`` when the pass finishes, so a kill *between* passes
     resumes the finished pass with zero streaming.
 
-    Observability: each megabatch dispatch gets an ``ingest.megabatch``
-    span (device-synced on the accumulator state, so the span measures the
-    reduction, not just async dispatch); transient-read retries absorbed
+    Observability: each megabatch gets an ``ingest.megabatch`` span (the
+    pass thread's own work on it: prep, copy, dispatch and, in the screen
+    pass, the readback; the pass spans end on completed device work), and
+    the feed's ``ingest.read`` / ``feed_wait`` / ``prep`` / ``h2d`` /
+    ``readback`` spans, all carrying the megabatch index ``b``
+    (``_feed``, `data.bow`, `kernels.ops`); transient-read retries absorbed
     by the store land in ``counters['io_retries']`` (registry:
     ``ingest.retries``); resume events land in ``ingest.resume.*`` and
     ``counters['resumed_megabatches']``; and the prefetch queue's stall
@@ -146,24 +182,19 @@ def _drain(store: SparseCorpus, acc, *, chunk_nnz, chunk_rows, megabatch,
             metrics.counter("ingest.resume.megabatches_skipped").inc(cursor)
             _count(counters, "resumed_megabatches", cursor)
     retries0 = getattr(store, "io_retry_count", 0)
-    it = store.iter_megabatches(
+    pstats: dict = {}
+    pprev: dict = {}
+    it = _feed(store.iter_megabatches(
         chunk_nnz=chunk_nnz, chunk_rows=chunk_rows, megabatch=megabatch,
         host_id=host_id, num_hosts=num_hosts,
         ring=max(2, prefetch_depth + 2),
         start_batch=start_batch,
-    )
-    pstats: dict = {}
-    pprev: dict = {}
-    if prefetch_depth > 0:
-        it = prefetch(it, size=prefetch_depth, stats=pstats)
+    ), start_batch=start_batch, prefetch_depth=prefetch_depth, stats=pstats)
     done = start_batch
     for mb in it:
         with trace.span("ingest.megabatch", kind=launch_key,
-                        chunks=int(mb.n_chunks)):
+                        chunks=int(mb.n_chunks), b=mb.index):
             acc.update_csr_batch(mb)
-            trace.device_sync(
-                tuple(getattr(acc, f) for f in acc._acc_fields)
-            )
         _bump(counters, **{launch_key: 1, "chunks": mb.n_chunks})
         # Stream prefetch stall/occupancy into the registry NOW, not at
         # pass end: a multi-hour Gram pass scraped over /metrics shows its

@@ -26,8 +26,12 @@ the sharded step, so the error bound is independent of both the chunk
 count and D).
 
 Observability: the whole drain runs under an ``ingest.shard_pass`` span
-(child of the usual ``ingest.screen_pass`` / ``ingest.gram_pass``), the
-``mesh.devices`` gauge records the topology, and per-device lane counters
+(child of the usual ``ingest.screen_pass`` / ``ingest.gram_pass``), with
+the single-device path's feed spans per superbatch (``ingest.read``,
+``feed_wait``, ``prep`` for the Gram pass's support remap, and ``h2d`` for
+the ``device_put`` calls and their block; no ``readback``, the partials
+stay on the devices), the ``mesh.devices`` gauge records the topology,
+and per-device lane counters
 (``ingest.shard.chunks`` / ``ingest.shard.nnz``) accumulate in per-lane
 registries merged into the global one at pass end via `Registry.merge` —
 the same pooling a real multi-process mesh would do over scraped
@@ -61,7 +65,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.distributed import psum_partials
 from repro.core.elimination import Screen, combine_screens
 from repro.data.bow import local_support_cols
-from repro.data.pipeline import prefetch
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref
 from repro.kernels.csr_gram import csr_gram_megabatch_pallas
@@ -70,7 +73,7 @@ from repro.launch.mesh import make_data_mesh
 from repro.obs import metrics, trace
 
 from .engine import (
-    DEFAULT_MEGABATCH, DEFAULT_PREFETCH, _bump, _count,
+    DEFAULT_MEGABATCH, DEFAULT_PREFETCH, _bump, _count, _feed,
     _stream_prefetch_stats, _reliability,
 )
 from .resume import DEFAULT_CHECKPOINT_EVERY, pass_fingerprint
@@ -98,6 +101,7 @@ class CSRSuperBatch(NamedTuple):
     n_chunks: int         # total real chunks across lanes
     lane_chunks: tuple    # per-lane real chunk counts
     lane_nnz: tuple       # per-lane real nnz
+    index: int = 0        # pass position of lane 0's megabatch (``b``)
 
 
 def _iter_superbatches(store: SparseCorpus, *, devices: int, chunk_nnz: int,
@@ -127,6 +131,7 @@ def _iter_superbatches(store: SparseCorpus, *, devices: int, chunk_nnz: int,
         for _ in range(ring)
     ]
     slot = 0
+    index = int(start_batch)
     done = False
     while not done:
         b = bufs[slot]
@@ -160,7 +165,9 @@ def _iter_superbatches(store: SparseCorpus, *, devices: int, chunk_nnz: int,
             values=b["values"], col_ids=b["col_ids"], seg_ids=b["seg_ids"],
             n_rows=b["n_rows"], nnz=b["nnz"], lanes=lanes, n_chunks=chunks,
             lane_chunks=tuple(lane_chunks), lane_nnz=tuple(lane_nnz),
+            index=index,
         )
+        index += lanes
         slot = (slot + 1) % ring
 
 
@@ -273,12 +280,13 @@ class MeshStats:
         self.count = 0
 
     def update_superbatch(self, sb: CSRSuperBatch) -> "MeshStats":
-        vals = jax.device_put(sb.values, self._ent_shard)
-        cols = jax.device_put(sb.col_ids, self._ent_shard)
         # The superbatch arrays are ring-buffer views; block on the
         # transfer before releasing them back to the packer (the same
         # rationale as ops._sync_host_inputs).
-        jax.block_until_ready((vals, cols))
+        with trace.span("ingest.h2d", b=sb.index):
+            vals = jax.device_put(sb.values, self._ent_shard)
+            cols = jax.device_put(sb.col_ids, self._ent_shard)
+            jax.block_until_ready((vals, cols))
         step = _stats_step(self.devices, self.n, _use_pallas(self.impl))
         self.sum, self.sumsq, self._err_sum, self._err_sumsq = step(
             self.sum, self.sumsq, self._err_sum, self._err_sumsq, vals, cols
@@ -367,11 +375,13 @@ class MeshGram:
         if self.support.size == 0:
             self.count += int(np.sum(sb.n_rows))
             return self
-        local = local_support_cols(self.support, sb.col_ids)
-        vals = jax.device_put(sb.values, self._ent_shard)
-        cols = jax.device_put(local, self._ent_shard)
-        segs = jax.device_put(sb.seg_ids, self._ent_shard)
-        jax.block_until_ready((vals, cols, segs))
+        with trace.span("ingest.prep", b=sb.index):
+            local = local_support_cols(self.support, sb.col_ids)
+        with trace.span("ingest.h2d", b=sb.index):
+            vals = jax.device_put(sb.values, self._ent_shard)
+            cols = jax.device_put(local, self._ent_shard)
+            segs = jax.device_put(sb.seg_ids, self._ent_shard)
+            jax.block_until_ready((vals, cols, segs))
         step = _gram_step(self.devices, self.chunk_rows,
                           int(self.support.size), _use_pallas(self.impl))
         self.g, self._err = step(self.g, self._err, vals, cols, segs)
@@ -482,29 +492,25 @@ def _mesh_drain(store: SparseCorpus, acc, *, devices, chunk_nnz, chunk_rows,
             metrics.counter("ingest.resume.megabatches_skipped").inc(cursor)
             _count(counters, "resumed_megabatches", cursor)
     retries0 = getattr(store, "io_retry_count", 0)
-    it = _iter_superbatches(
+    pstats: dict = {}
+    pprev: dict = {}
+    it = _feed(_iter_superbatches(
         store, devices=D, chunk_nnz=chunk_nnz, chunk_rows=chunk_rows,
         megabatch=megabatch, host_id=host_id, num_hosts=num_hosts,
         ring=max(2, prefetch_depth + 2), start_batch=start_batch,
-    )
-    pstats: dict = {}
-    pprev: dict = {}
-    if prefetch_depth > 0:
-        it = prefetch(it, size=prefetch_depth, stats=pstats)
+    ), start_batch=start_batch, prefetch_depth=prefetch_depth, stats=pstats)
     lane_regs = [metrics.Registry() for _ in range(D)]
     done = start_batch
     with trace.span("ingest.shard_pass", kind=launch_key, devices=D,
                     megabatch=megabatch):
         for sb in it:
             with trace.span("ingest.megabatch", kind=launch_key,
-                            chunks=int(sb.n_chunks), lanes=int(sb.lanes)):
+                            chunks=int(sb.n_chunks), lanes=int(sb.lanes),
+                            b=sb.index):
                 # Fault seam: lets tests kill THIS dispatch the way a real
                 # XLA runtime error would, exercising the degrade ladder.
                 kernel_ops.solver_fault_before(f"mesh.{kind or launch_key}")
                 acc.update_superbatch(sb)
-                trace.device_sync(
-                    tuple(getattr(acc, f) for f in acc._acc_fields)
-                )
             _bump(counters, **{launch_key: 1, "chunks": sb.n_chunks})
             for d in range(sb.lanes):
                 lane_regs[d].counter("ingest.shard.chunks").inc(

@@ -198,6 +198,7 @@ class CSRMegaBatch(NamedTuple):
     n_rows: np.ndarray     # (C,) int32 real rows per slot (0 = unused slot)
     nnz: np.ndarray        # (C,) int64 real entries per slot
     n_chunks: int          # real chunks packed (<= C)
+    index: int = 0         # this batch's position in the pass (``b``)
 
 
 def _fill_slot(values, col_ids, seg_ids, vals, cols, row_ptr, r, stop):
@@ -664,6 +665,7 @@ class SparseCorpus:
         ]
         b = 0
         slot = 0
+        index = int(start_batch)
         row_offset_v = np.zeros(C, np.int64)
         n_rows_v = np.zeros(C, np.int32)
         nnz_v = np.zeros(C, np.int64)
@@ -680,7 +682,7 @@ class SparseCorpus:
             return CSRMegaBatch(
                 values=values, col_ids=col_ids, seg_ids=seg_ids,
                 row_offset=row_offset_v.copy(), n_rows=n_rows_v.copy(),
-                nnz=nnz_v.copy(), n_chunks=n_slots,
+                nnz=nnz_v.copy(), n_chunks=n_slots, index=index,
             )
 
         for vals, cols, row_ptr, row_offset, r, stop in self._iter_packed(
@@ -697,6 +699,7 @@ class SparseCorpus:
             if slot == C:
                 yield emit(C)
                 slot = 0
+                index += 1
                 if reuse_buffers:
                     b = (b + 1) % len(buffers)
                 else:
