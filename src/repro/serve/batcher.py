@@ -1,20 +1,27 @@
 """Microbatching front-end: ragged request stream -> fixed-shape batches.
 
 Serving traffic arrives one variable-length document at a time, but the
-jitted projector wants one shape forever (a new (B, n) means an XLA
+jitted projector wants one shape forever (a new shape means an XLA
 recompile mid-traffic — the latency cliff this module exists to prevent).
 The batcher therefore coalesces up to ``max_batch`` requests (waiting at
-most ``max_wait_ms`` after the first), scatters them into a zero-padded
-``(max_batch, n)`` count matrix, and pushes batches through
-``data.pipeline.prefetch`` so host-side batch assembly overlaps device
-compute — the same producer/consumer idiom the LM input pipeline uses.
+most ``max_wait_ms`` after the first) into a ``SparseBatch``: the live
+requests' (row, word id, count) entries, concatenated, with the padded
+row count ``max_batch``.  No n-length buffer is made on the way: a
+projector that knows the type (``TopicProjector``) folds the entries into
+its own support columns, and any other projector gets the zero-padded
+``(max_batch, n)`` float32 matrix through ``SparseBatch.dense`` /
+``np.asarray`` — the dense fallback, counted by ``serve.dense_batches``.
+Batches go through ``data.pipeline.prefetch`` so host-side batch assembly
+overlaps device compute — the same producer/consumer idiom the LM input
+pipeline uses.
 
 Every request resolves a ``concurrent.futures.Future`` with its (k,) score
 vector; per-request wall latency feeds the p50/p99 report.  Each
 request's wait from ``submit`` until the collector pops it goes into the
-``serve.queue_wait_s`` histogram; building a batch is a ``serve.build``
-span on the collector thread, serving it a ``serve.batch`` span on the
-server thread, and the two share the batch's ``seq``.
+``serve.queue_wait_s`` histogram; building a batch (validating and
+concatenating its requests) is a ``serve.build`` span on the collector
+thread, serving it a ``serve.batch`` span on the server thread, and the
+two share the batch's ``seq``.
 """
 from __future__ import annotations
 
@@ -53,6 +60,44 @@ class RequestTimeout(TimeoutError):
 class RequestShed(RuntimeError):
     """The submit queue is at ``cfg.max_queue``: the batcher rejects new
     work at the door instead of queueing latency it can never repay."""
+
+
+@dataclass(frozen=True)
+class SparseBatch:
+    """One microbatch as the live requests' entries, not an n-wide matrix.
+
+    Entry e is ``counts[e]`` occurrences of word ``word_ids[e]`` in row
+    ``row_ids[e]``; rows ``live`` .. ``rows - 1`` are padding and hold no
+    entries.  Each request's entries keep their submitted order, so every
+    fold of them (``dense``, a projector's support columns) sums a
+    repeated word id in the same order.
+    """
+
+    rows: int               # padded row count: the batcher's max_batch
+    n: int                  # vocabulary width
+    row_ids: np.ndarray     # (nnz,) int32
+    word_ids: np.ndarray    # (nnz,) int64, each in [0, n)
+    counts: np.ndarray      # (nnz,) float32
+    live: int               # rows that hold a request
+
+    @classmethod
+    def empty(cls, rows: int, n: int) -> "SparseBatch":
+        return cls(rows, n, np.zeros(0, np.int32), np.zeros(0, np.int64),
+                   np.zeros(0, np.float32), 0)
+
+    def dense(self, rows: int | None = None) -> np.ndarray:
+        """The zero-padded ``(rows, n)`` float32 count matrix (``rows``
+        defaults to the padded count) — the fallback for projectors and
+        observers that take arrays.  Counted by ``serve.dense_batches``."""
+        metrics.counter("serve.dense_batches").inc()
+        X = np.zeros((self.rows if rows is None else rows, self.n),
+                     np.float32)
+        np.add.at(X, (self.row_ids, self.word_ids), self.counts)
+        return X
+
+    def __array__(self, dtype=None, copy=None):
+        X = self.dense()
+        return X if dtype is None else X.astype(dtype, copy=False)
 
 
 class LatencyStats:
@@ -114,12 +159,17 @@ class _Request:
 
 
 class MicroBatcher:
-    """Queue -> coalesce -> pad -> project -> resolve futures.
+    """Queue -> coalesce -> ``SparseBatch`` -> project -> resolve futures.
 
-    ``projector`` is any object with ``.project((B, n) array) -> (B, k)``
+    ``projector`` is any object with ``.project(batch) -> (B, k)`` scores
     (normally the active ``TopicProjector``; pass a registry-backed lambda
-    for hot-swappable serving).  ``observer`` (optional) receives each
-    batch's *live* rows — the drift monitor taps traffic here.
+    for hot-swappable serving).  It is handed the ``SparseBatch`` of
+    ``max_batch`` rows: ``TopicProjector`` serves it from its support
+    columns, and a projector that takes arrays densifies it with
+    ``np.asarray`` / ``jnp.asarray`` (``SparseBatch.__array__``).
+    ``observer`` (optional) receives each batch's *live* rows as a dense
+    ``(live, n)`` array, built after the batch's futures resolve — the
+    drift monitor taps traffic here.
     """
 
     def __init__(self, projector, n_features: int,
@@ -184,8 +234,7 @@ class MicroBatcher:
         return True
 
     def _collect(self):
-        """Yield (requests, padded (max_batch, n) matrix, seq) until
-        stopped."""
+        """Yield (requests, SparseBatch, seq) until stopped."""
         cfg = self.cfg
         while not self._stop.is_set():
             try:
@@ -217,8 +266,7 @@ class MicroBatcher:
             seq = self._seq
             self._seq += 1
             with trace.span("serve.build", batch=len(reqs), seq=seq):
-                X = np.zeros((cfg.max_batch, self.n), np.float32)
-                live = []
+                live, words, counts = [], [], []
                 for r in reqs:
                     try:   # a malformed request fails ITS future, not the loop
                         w = r.word_ids
@@ -228,22 +276,34 @@ class MicroBatcher:
                             # vocab tail via numpy indexing — reject them
                             raise IndexError(
                                 f"word ids outside [0, {self.n})")
-                        np.add.at(X[len(live)], w, r.counts)
-                        live.append(r)
+                        c = r.counts
+                        if c.shape != w.shape:   # a scalar count, say
+                            c = np.broadcast_to(c, w.shape)
                     except (IndexError, ValueError, TypeError) as e:
-                        X[len(live)] = 0.0   # scatter may have partially landed
                         r.future.set_exception(e)
+                        continue
+                    live.append(r)
+                    words.append(w.ravel())
+                    counts.append(c.ravel())
+                if live:
+                    sizes = [w.size for w in words]
+                    batch = SparseBatch(
+                        rows=cfg.max_batch, n=self.n,
+                        row_ids=np.repeat(
+                            np.arange(len(live), dtype=np.int32), sizes),
+                        word_ids=np.concatenate(words),
+                        counts=np.concatenate(counts), live=len(live))
             if live:
-                yield live, X, seq
+                yield live, batch, seq
 
     def _serve_loop(self):
         # Runs on the server thread: spans opened here land on that
         # thread's own root timeline (see obs.trace thread model).
-        for reqs, X, seq in prefetch(self._collect(),
-                                     size=self.cfg.prefetch_depth):
+        for reqs, batch, seq in prefetch(self._collect(),
+                                         size=self.cfg.prefetch_depth):
             with trace.span("serve.batch", batch=len(reqs), seq=seq):
                 try:
-                    scores = np.asarray(self.projector.project(X))
+                    scores = np.asarray(self.projector.project(batch))
                 except Exception as e:      # fail the waiting futures, not us
                     for r in reqs:
                         r.future.set_exception(e)
@@ -260,7 +320,7 @@ class MicroBatcher:
                 # *before* deadline/shed tallies start moving
                 metrics.gauge("serve.queue_depth").set(self._q.qsize())
                 if self.observer is not None:  # off the response critical path
-                    self.observer(X[: len(reqs)])
+                    self.observer(batch.dense(rows=batch.live))
 
     def snapshot(self) -> dict:
         """Latency percentiles plus the degradation tallies — the one
@@ -282,10 +342,10 @@ class MicroBatcher:
 
     def start(self) -> "MicroBatcher":
         assert self._thread is None, "already started"
-        # Warm-up: trace/compile the (max_batch, n) program before traffic
-        # arrives, so the first real batch doesn't eat the compile latency.
-        self.projector.project(np.zeros((self.cfg.max_batch, self.n),
-                                        np.float32))
+        # Warm-up: trace/compile the program traffic will use (an empty
+        # batch of max_batch rows) before traffic arrives, so the first
+        # real batch doesn't eat the compile latency.
+        self.projector.project(SparseBatch.empty(self.cfg.max_batch, self.n))
         self._thread = threading.Thread(target=self._serve_loop, daemon=True)
         self._thread.start()
         return self

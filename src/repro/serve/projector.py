@@ -8,6 +8,15 @@ different cardinalities reuse the same jitted program — and ``TopicProjector``
 pushes batches through ``kernels.ops.sparse_project`` (the Pallas
 gather-matvec on TPU, its jnp gather oracle elsewhere).
 
+``TopicProjector.project`` follows its input's type.  A dense ``(B, n)``
+array is copied as it is and gathered from.  A ``serve.batcher.SparseBatch``
+(what the microbatcher hands it) is folded on the host into a
+``(rows, ncols)`` matrix of the model's own support columns — ``ncols`` the
+number of distinct support words rounded up to 128 — and the gather reads
+the remapped slots there, so a batch's copy is ``rows * ncols`` floats
+instead of ``rows * n`` and no n-wide matrix is built.  The scores are the
+same sums in the same slot order on either path.
+
 Luss & d'Aspremont (2008): sparse PCs double as feature selectors / cluster
 assigners, so the projector also exposes ``assign_topics`` (argmax score)
 and a sparse-document path ``project_docs`` that maps raw (word_id, count)
@@ -24,7 +33,8 @@ import numpy as np
 
 from repro.core.spca import PCResult
 from repro.kernels import ops
-from repro.obs import trace
+from repro.obs import metrics, trace
+from repro.serve.batcher import SparseBatch
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -85,24 +95,39 @@ def pack_components(
 class TopicProjector:
     """Jitted batched document->topic projection for one packed model.
 
-    The projection function is jitted once per (batch, n) shape; the
-    microbatcher always presents one fixed shape, so steady-state serving
-    never recompiles.  ``trace_count`` counts retraces (the shape-stability
-    tests assert it stays at 1).
+    The projection function is jitted once per input shape; the
+    microbatcher always presents one fixed ``SparseBatch`` shape, so
+    steady-state serving never recompiles.  ``trace_count`` counts
+    retraces (the shape-stability tests assert it stays at 1).
+
+    The projector owns its support-column map (word id -> compact column,
+    -1 off the support), so a hot swap never serves a batch with another
+    model's columns.
     """
 
     def __init__(self, pack: ProjectorPack, *, impl: str = "auto"):
         self.pack = pack
         self.impl = impl
         self.trace_count = 0
-        sidx = jnp.asarray(pack.support_idx)
         vals = jnp.asarray(pack.values)
 
-        def _project(X):
-            self.trace_count += 1  # python side effect: fires per trace only
-            return ops.sparse_project(X, sidx, vals, impl=impl)
+        def _jit(sidx):
+            def _project(X):
+                self.trace_count += 1  # python side effect: per trace only
+                return ops.sparse_project(X, sidx, vals, impl=impl)
+            return jax.jit(_project)
 
-        self._project = jax.jit(_project)
+        self._project = _jit(jnp.asarray(pack.support_idx))
+        # Support columns: each distinct live support word owns one column
+        # (shared when supports overlap); padded slots keep weight 0.0 and
+        # read column 0.
+        live_slot = pack.values != 0
+        words = np.unique(pack.support_idx[live_slot])
+        self._ncols = _round_up(max(words.size, 1), 128)
+        self._word_col = np.full(pack.n_features, -1, np.int32)
+        self._word_col[words] = np.arange(words.size, dtype=np.int32)
+        col_idx = np.where(live_slot, self._word_col[pack.support_idx], 0)
+        self._project_cols = _jit(jnp.asarray(col_idx.astype(np.int32)))
         # Word id -> packed slot(s), sorted-CSR style, for the sparse-doc
         # fast path.  A word may own several slots when component supports
         # overlap (Hotelling 'project' deflation does not guarantee the
@@ -114,12 +139,30 @@ class TopicProjector:
         self._sorted_slots = live[order]         # (nnz,) their flat slots
 
     def project(self, X) -> jax.Array:
-        """(B, n) counts -> (B, k) scores.  The batch's copy to the device
-        is a ``serve.h2d`` span, which ends on the landed copy while
-        tracing (and on its dispatch otherwise)."""
+        """(B, n) counts, or a ``SparseBatch`` of B rows -> (B, k) scores.
+        The batch's copy to the device is a ``serve.h2d`` span, which ends
+        on the landed copy while tracing (and on its dispatch otherwise)."""
+        if isinstance(X, SparseBatch):
+            return self._project_batch(X)
         with trace.span("serve.h2d"):
             X = trace.device_sync(jnp.asarray(X))
         return self._project(X)
+
+    def _project_batch(self, batch: SparseBatch) -> jax.Array:
+        """Fold the batch's entries into its support columns, copy that
+        ``(rows, ncols)`` matrix and gather from it.  Entries off the
+        support are dropped; ``serve.support_entries`` over
+        ``serve.batch_entries`` is the share kept."""
+        col = self._word_col[batch.word_ids]
+        on = col >= 0
+        Xc = np.zeros((batch.rows, self._ncols), np.float32)
+        np.add.at(Xc, (batch.row_ids[on], col[on]), batch.counts[on])
+        metrics.counter("serve.compact_batches").inc()
+        metrics.counter("serve.batch_entries").inc(int(col.size))
+        metrics.counter("serve.support_entries").inc(int(on.sum()))
+        with trace.span("serve.h2d"):
+            Xc = trace.device_sync(jnp.asarray(Xc))
+        return self._project_cols(Xc)
 
     def project_docs(self, docs) -> np.ndarray:
         """Sparse path: ``docs`` is a list of (word_ids, counts) pairs.

@@ -13,10 +13,12 @@ from repro.core.spca import PCResult
 from repro.data.corpus import make_corpus
 from repro.data.pipeline import prefetch
 from repro.kernels import ops, ref
+from repro.obs import metrics
 from repro.serve import (
     BatcherConfig, DriftMonitor, MicroBatcher, ModelRegistry, TopicProjector,
     pack_components,
 )
+from repro.serve.batcher import SparseBatch
 
 
 def _fake_components(n, k, card, seed=0, lam=1.0):
@@ -98,6 +100,85 @@ def test_projector_sparse_doc_path_with_overlapping_supports():
         proj.project_docs(docs), np.asarray(proj.project(X)),
         rtol=1e-5, atol=1e-5,
     )
+
+
+def _sparse_batch(docs, rows, n):
+    """The SparseBatch the collector builds from (word_ids, counts) docs."""
+    sizes = [len(w) for w, _ in docs]
+    return SparseBatch(
+        rows=rows, n=n,
+        row_ids=np.repeat(np.arange(len(docs), dtype=np.int32), sizes),
+        word_ids=np.concatenate([np.asarray(w, np.int64) for w, _ in docs]),
+        counts=np.concatenate([np.asarray(c, np.float32) for _, c in docs]),
+        live=len(docs))
+
+
+def _dense_rows(docs, rows, n):
+    """The dense batch as the collector used to build it, row by row."""
+    X = np.zeros((rows, n), np.float32)
+    for i, (w, c) in enumerate(docs):
+        np.add.at(X[i], np.asarray(w, np.int64), np.asarray(c, np.float32))
+    return X
+
+
+def _overlapping_components(n, card=4, seed=5):
+    rng = np.random.default_rng(seed)
+    shared = np.array([7, 42])
+    results = []
+    for c in range(3):
+        extra = 50 + c * card + np.arange(card - shared.size)
+        sup = np.sort(np.concatenate([shared, extra]))
+        x = np.zeros(n)
+        x[sup] = rng.normal(size=card)
+        results.append(PCResult(x=x, support=sup, lam=1.0, variance=1.0,
+                                cardinality=card, reduced_n=card, gap=0.0))
+    return results
+
+
+@pytest.mark.parametrize("case", [
+    "disjoint", "disjoint_pallas", "overlapping", "no_support_word",
+    "repeated_ids", "wide_support", "partial_rows",
+])
+def test_projector_sparse_batch_matches_dense(case):
+    """project(SparseBatch) folds entries into the support columns; its
+    scores equal project(dense) bit for bit: same products, same slot
+    order."""
+    n, rows = 600, 16
+    rng = np.random.default_rng(sum(map(ord, case)))
+    if case == "overlapping":
+        results = _overlapping_components(n)
+    elif case == "wide_support":             # 150 support words: 2 x 128
+        results = _fake_components(n, 30, 5, seed=3)
+    else:
+        results = _fake_components(n, 4, 5, seed=1)
+    pack = pack_components(results, n_features=n)
+    proj = TopicProjector(pack, impl="pallas" if case.endswith("pallas")
+                          else "ref")
+    support = np.unique(pack.support_idx[pack.values != 0])
+    off = np.setdiff1d(np.arange(n), support)
+    live = 5 if case == "partial_rows" else rows
+    docs = []
+    for d in range(live):
+        if case == "no_support_word" and d % 2:
+            w = rng.choice(off, size=30, replace=False)
+        else:
+            w = np.concatenate([rng.choice(off, size=30, replace=False),
+                                rng.choice(support, size=4, replace=False)])
+            if case == "repeated_ids":
+                w = np.concatenate([w, w[-3:], w[:2]])
+            rng.shuffle(w)
+        docs.append((w, rng.integers(1, 5, size=w.size).astype(np.float32)))
+    X = _dense_rows(docs, rows, n)
+    batch = _sparse_batch(docs, rows, n)
+    np.testing.assert_array_equal(batch.dense(), X)
+    got = np.asarray(proj.project(batch))
+    want = np.asarray(proj.project(X))
+    assert got.shape == (rows, pack.k)
+    np.testing.assert_array_equal(got, want)
+    assert want[: live].any() and not got[live:].any()
+    if case == "no_support_word":
+        assert not got[1:live:2].any()
+    assert proj._ncols == (256 if case == "wide_support" else 128)
 
 
 def test_pack_components_shape_stable_across_cardinality_wobble():
@@ -197,6 +278,145 @@ def test_batcher_shape_stability_across_ragged_requests():
     snap = mb.stats.snapshot()
     assert snap["count"] == 100
     assert snap["p99_ms"] >= snap["p50_ms"] >= 0.0
+
+
+def _queued_docs(mb, n, count, seed):
+    """Submit ``count`` ragged docs to a not-yet-started batcher (so the
+    batches it forms are the queue in order, ``max_batch`` at a time), one
+    with repeated word ids and one malformed; return the well-formed
+    docs in submit order."""
+    rng = np.random.default_rng(seed)
+    docs = []
+    for i in range(count):
+        w = rng.choice(n, size=int(rng.integers(1, 40)), replace=False)
+        if i == 3:
+            w = np.concatenate([w, w[:2]])
+        c = rng.integers(1, 4, size=w.size).astype(np.float32)
+        docs.append((w, c))
+    futs = [mb.submit(w, c) for w, c in docs]
+    bad = mb.submit([n + 1], [1.0])          # fails alone, takes no row
+    return docs, futs, bad
+
+
+def test_batcher_hands_projector_a_sparse_batch():
+    """The projector receives SparseBatch entries of max_batch rows: no
+    (max_batch, n) buffer is built anywhere on the way."""
+    n, k, max_batch = 500, 3, 8
+
+    class Recorder:
+        def __init__(self):
+            self.batches = []
+
+        def project(self, X):
+            self.batches.append(X)
+            return np.zeros((X.rows, k), np.float32)
+
+    rec = Recorder()
+    mb = MicroBatcher(rec, n, BatcherConfig(max_batch=max_batch,
+                                            max_wait_ms=1.0))
+    with metrics.use_registry() as reg:
+        docs, futs, bad = _queued_docs(mb, n, 20, seed=4)
+        with mb:
+            for f in futs:
+                assert f.result(timeout=30).shape == (k,)
+            with pytest.raises(IndexError):
+                bad.result(timeout=30)
+        assert reg.value("serve.dense_batches", 0) == 0
+    warm, served = rec.batches[0], rec.batches[1:]
+    assert isinstance(warm, SparseBatch) and warm.live == 0
+    assert warm.rows == max_batch and warm.word_ids.size == 0
+    assert [b.live for b in served] == [8, 8, 4]
+    for i, b in enumerate(served):
+        assert isinstance(b, SparseBatch)
+        assert (b.rows, b.n) == (max_batch, n)
+        chunk = docs[i * max_batch:(i + 1) * max_batch]
+        nnz = sum(w.size for w, _ in chunk)
+        for a in (b.row_ids, b.word_ids, b.counts):
+            assert a.shape == (nnz,)
+        np.testing.assert_array_equal(
+            b.dense(), _dense_rows(chunk, max_batch, n))
+
+
+def test_batcher_densifies_for_array_only_projector():
+    """A projector that takes arrays gets the same zero-padded
+    (max_batch, n) float32 matrix as before, and serve.dense_batches
+    counts each densification (the warm-up's included)."""
+    n, max_batch = 300, 4
+    W = np.random.default_rng(0).normal(size=(n, 2)).astype(np.float32)
+
+    class ArrayProjector:
+        def __init__(self):
+            self.seen = []
+
+        def project(self, X):
+            X = np.asarray(X)
+            self.seen.append(X)
+            return X @ W
+
+    ap = ArrayProjector()
+    mb = MicroBatcher(ap, n, BatcherConfig(max_batch=max_batch,
+                                           max_wait_ms=1.0))
+    with metrics.use_registry() as reg:
+        docs, futs, _ = _queued_docs(mb, n, 10, seed=6)
+        with mb:
+            got = np.stack([f.result(timeout=30) for f in futs])
+        assert reg.value("serve.dense_batches") == len(ap.seen) == 4
+    assert all(X.shape == (max_batch, n) and X.dtype == np.float32
+               for X in ap.seen)
+    assert not ap.seen[0].any()                      # the warm-up
+    for i, X in enumerate(ap.seen[1:]):
+        np.testing.assert_array_equal(
+            X, _dense_rows(docs[i * max_batch:(i + 1) * max_batch],
+                           max_batch, n))
+    want = [(_dense_rows(docs[i:i + max_batch], max_batch, n) @ W)
+            [:len(docs[i:i + max_batch])] for i in range(0, 10, max_batch)]
+    np.testing.assert_array_equal(got, np.concatenate(want))
+
+
+def test_batcher_observer_receives_dense_live_rows():
+    n = 250
+    pack = pack_components(_fake_components(n, 2, 4), n_features=n)
+    seen = []
+    mb = MicroBatcher(TopicProjector(pack, impl="ref"), n,
+                      BatcherConfig(max_batch=4, max_wait_ms=1.0),
+                      observer=seen.append)
+    docs, futs, _ = _queued_docs(mb, n, 10, seed=8)
+    with mb:
+        for f in futs:
+            f.result(timeout=30)
+    assert [X.shape for X in seen] == [(4, n), (4, n), (2, n)]
+    np.testing.assert_array_equal(np.concatenate(seen),
+                                  _dense_rows(docs, len(docs), n))
+
+
+def test_batcher_serves_topic_projector_from_support_columns():
+    """Every batch a TopicProjector serves takes the compact path (no
+    densification), with one trace under ragged traffic, and the entry
+    counters see every entry."""
+    n = 400
+    pack = pack_components(_fake_components(n, 3, 5), n_features=n)
+    proj = TopicProjector(pack, impl="ref")
+    mb = MicroBatcher(proj, n, BatcherConfig(max_batch=8, max_wait_ms=1.0))
+    docs, futs, _ = _queued_docs(mb, n, 30, seed=9)
+    with metrics.use_registry() as reg:
+        mb.start()
+        try:
+            got = np.stack([f.result(timeout=30) for f in futs])
+        finally:
+            mb.stop()
+        batches = reg.value("serve.batches")
+        assert batches == 4
+        assert reg.value("serve.compact_batches") == batches + 1  # warm-up
+        assert reg.value("serve.dense_batches", 0) == 0
+        assert reg.value("serve.batch_entries") == sum(w.size
+                                                       for w, _ in docs)
+        support = set(pack.support_idx[pack.values != 0].tolist())
+        assert reg.value("serve.support_entries") == sum(
+            int(np.isin(w, list(support)).sum()) for w, _ in docs)
+    assert proj.trace_count == 1
+    want = [np.asarray(proj.project(_dense_rows(docs[i:i + 8], 8, n)))
+            [:len(docs[i:i + 8])] for i in range(0, 30, 8)]
+    np.testing.assert_array_equal(got, np.concatenate(want))
 
 
 def test_batcher_scores_match_direct_projection():

@@ -121,6 +121,16 @@ def test_projector_kernel_compiles(one_chip, B):
         _s(one_chip, (P,), jnp.int32), _s(one_chip, (P,)))
 
 
+def test_projector_kernel_compiles_on_support_columns(one_chip):
+    """The microbatcher's batch as a TopicProjector serves it: 64 rows of
+    its support columns, one 128-lane block."""
+    P = 5 * 8
+    _compile(
+        lambda X, i, c, v: sparse_project_pallas(X, i, c, v, 5),
+        _s(one_chip, (64, 128)), _s(one_chip, (P,), jnp.int32),
+        _s(one_chip, (P,), jnp.int32), _s(one_chip, (P,)))
+
+
 # ---------------------------------------------------------------- VMEM plans
 
 
