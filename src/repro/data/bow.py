@@ -36,20 +36,46 @@ import numpy as np
 
 from repro.core.elimination import Screen, select_support
 from repro.kernels import ops
-from repro.obs import trace
+from repro.obs import metrics, trace
 
 
-def local_support_cols(support: np.ndarray, col_ids: np.ndarray) -> np.ndarray:
-    """Map global column ids to support positions (support is sorted — it
-    comes from flatnonzero); entries off the support get the >= n_hat
-    sentinel the kernel/oracle drop.  Vectorized over any entry-array shape
-    (one chunk, a megabatch, or a mesh superbatch).  The single
-    implementation behind ``StreamingGram`` and the mesh Gram pass."""
-    support = np.asarray(support)
-    k = support.size
-    pos = np.searchsorted(support, col_ids)
-    pos_c = np.minimum(pos, max(k - 1, 0))
-    return np.where(support[pos_c] == col_ids, pos_c, k).astype(np.int32)
+class SupportRemap:
+    """Global column id -> position in a Gram support, through an int32
+    table built once per support; entries off the support get the
+    ``n_hat`` sentinel the kernel/oracle drop.  Vectorized over any
+    entry-array shape (one chunk, a megabatch, or a mesh superbatch).  The
+    single implementation behind ``StreamingGram`` and the mesh Gram pass.
+
+    The table ends one past the largest support word, and the gather
+    clips into it through a ``uint32`` view, so negative ids and ids past
+    the support both land on the sentinel."""
+
+    def __init__(self, support: np.ndarray):
+        support = np.asarray(support)
+        self.n_hat = int(support.size)
+        top = int(support.max()) + 2 if support.size else 1
+        self.table = np.full(top, self.n_hat, np.int32)
+        self.table[support] = np.arange(self.n_hat, dtype=np.int32)
+
+    def cols(self, col_ids: np.ndarray) -> np.ndarray:
+        """Support positions of int32 ``col_ids`` (any shape)."""
+        ids = np.asarray(col_ids, np.int32)
+        return np.take(self.table, ids.view(np.uint32), mode="clip")
+
+    def __call__(self, col_ids: np.ndarray, nnz) -> np.ndarray:
+        """`cols`, counting the ``nnz`` live entries into
+        ``ingest.gram_entries`` and those on the support into
+        ``ingest.gram_support_entries``.  Padded slots hold col 0, so they
+        are taken off the on-support count when word 0 is on the
+        support."""
+        local = self.cols(col_ids)
+        live = int(np.sum(nnz))
+        on = int(np.count_nonzero(local < self.n_hat))
+        if self.table[0] < self.n_hat:
+            on -= local.size - live
+        metrics.counter("ingest.gram_entries").inc(live)
+        metrics.counter("ingest.gram_support_entries").inc(on)
+        return local
 
 
 class StreamingAccumulator:
@@ -214,6 +240,7 @@ class StreamingGram(StreamingAccumulator):
         self.count = 0
         self.impl = impl
         self.chunk_rows = chunk_rows
+        self._remap = SupportRemap(self.support)
 
     def _acc(self, delta) -> None:
         """Fold one partial gram into ``g`` — compensated when f32."""
@@ -234,13 +261,6 @@ class StreamingGram(StreamingAccumulator):
         self.count += batch.shape[0]
         return self
 
-    def _local_cols(self, col_ids: np.ndarray) -> np.ndarray:
-        """Map global column ids to support positions (support is sorted —
-        it comes from flatnonzero); entries off the support get the
-        >= n_hat sentinel the kernel/oracle drop.  Vectorized over any
-        entry-array shape (one chunk or a whole megabatch)."""
-        return local_support_cols(self.support, col_ids)
-
     def _check_rows(self, n_rows: int) -> None:
         if n_rows > self.chunk_rows:
             raise ValueError(
@@ -255,7 +275,7 @@ class StreamingGram(StreamingAccumulator):
             self.count += chunk.n_rows
             return self
         self._acc(ops.csr_gram(
-            chunk.values, self._local_cols(chunk.col_ids), chunk.seg_ids,
+            chunk.values, self._remap(chunk.col_ids, chunk.nnz), chunk.seg_ids,
             n_rows=self.chunk_rows, n_hat=self.support.size, impl=self.impl,
             nnz=chunk.nnz,
         ))
@@ -270,7 +290,7 @@ class StreamingGram(StreamingAccumulator):
             self.count += int(np.sum(mb.n_rows))
             return self
         with trace.span("ingest.prep", b=mb.index):
-            local = self._local_cols(mb.col_ids)
+            local = self._remap(mb.col_ids, mb.nnz)
         self._acc(ops.csr_gram_batched(
             mb.values, local, mb.seg_ids,
             n_rows=self.chunk_rows, n_hat=self.support.size, impl=self.impl,

@@ -64,7 +64,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.distributed import psum_partials
 from repro.core.elimination import Screen, combine_screens
-from repro.data.bow import local_support_cols
+from repro.data.bow import SupportRemap
 from repro.kernels import ops as kernel_ops
 from repro.kernels import ref
 from repro.kernels.csr_gram import csr_gram_megabatch_pallas
@@ -370,13 +370,14 @@ class MeshGram:
         self.g = jax.device_put(z, self._acc_shard)
         self._err = jax.device_put(z, self._acc_shard)
         self.count = 0
+        self._remap = SupportRemap(self.support)
 
     def update_superbatch(self, sb: CSRSuperBatch) -> "MeshGram":
         if self.support.size == 0:
             self.count += int(np.sum(sb.n_rows))
             return self
         with trace.span("ingest.prep", b=sb.index):
-            local = local_support_cols(self.support, sb.col_ids)
+            local = self._remap(sb.col_ids, sb.nnz)
         with trace.span("ingest.h2d", b=sb.index):
             vals = jax.device_put(sb.values, self._ent_shard)
             cols = jax.device_put(local, self._ent_shard)

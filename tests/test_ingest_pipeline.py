@@ -6,15 +6,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _hypothesis_compat import given, settings, st
+
 from repro.core import SPCAConfig, fit_components
-from repro.data.bow import StreamingGram, StreamingStats
+from repro.data.bow import StreamingGram, StreamingStats, SupportRemap
 from repro.data.pipeline import prefetch
 from repro.data import make_corpus
 from repro.kernels import ops, ref
 from repro.kernels.csr_gram import csr_gram_batched_pallas
 from repro.kernels.csr_stats import csr_column_stats_pallas
+from repro.obs import metrics
 from repro.sparse import write_corpus
 from repro.sparse.engine import sparse_feature_variances, sparse_stats
+from repro.sparse.store import CSRMegaBatch
 
 
 @pytest.fixture(scope="module")
@@ -333,6 +337,123 @@ def test_streaming_gram_f32_merge_keeps_compensation():
     for other in parts[1:]:
         pooled.merge(other)
     np.testing.assert_allclose(pooled.finalize(), 1e8 + 1500.0, rtol=1e-9)
+
+
+# ---------------------------------------------------------- support remap
+
+def _searchsorted_cols(support, col_ids):
+    """The remap before `SupportRemap`: one searchsorted of every entry
+    into the sorted support, kept as the table's oracle."""
+    support = np.asarray(support)
+    k = support.size
+    pos = np.searchsorted(support, col_ids)
+    pos_c = np.minimum(pos, max(k - 1, 0))
+    return np.where(support[pos_c] == col_ids, pos_c, k).astype(np.int32)
+
+
+_N_WORDS = 2500
+_SUPPORTS = {
+    "empty": np.array([], np.int64),
+    "one": np.array([7]),
+    "one_word0": np.array([0]),
+    "220": np.sort(np.random.default_rng(1).choice(
+        np.arange(1, _N_WORDS), 220, replace=False)),
+    "220_word0": np.sort(np.concatenate([[0], np.random.default_rng(2).choice(
+        np.arange(1, _N_WORDS), 219, replace=False)])),
+}
+
+
+@pytest.mark.parametrize("shape", [(1024,), (4, 256), (2, 3, 128)],
+                         ids=["chunk", "megabatch", "superbatch"])
+@pytest.mark.parametrize("kind", list(_SUPPORTS))
+@settings(max_examples=25, deadline=None)
+@given(seed=st.integers(0, 2**32 - 1))
+def test_support_remap_matches_searchsorted(kind, shape, seed):
+    """The table gather equals the searchsorted remap for every int32 id:
+    negative ids, 0, the last support word and the one past it, ids at
+    and past ``n``; an empty support sends everything to the sentinel 0."""
+    support = _SUPPORTS[kind]
+    rng = np.random.default_rng(seed)
+    ids = np.where(rng.random(int(np.prod(shape))) < 0.5,
+                   rng.zipf(1.2, int(np.prod(shape))) - 1,
+                   rng.integers(-2**31, 2**31, int(np.prod(shape))))
+    top = int(support[-1]) if support.size else 0
+    edges = [-2**31, -1, 0, 1, top, top + 1, _N_WORDS, _N_WORDS + 1,
+             2**31 - 1]
+    ids[:len(edges)] = edges
+    ids = np.clip(ids, -2**31, 2**31 - 1).astype(np.int32).reshape(shape)
+    got = SupportRemap(support).cols(ids)
+    want = (_searchsorted_cols(support, ids) if support.size
+            else np.zeros(shape, np.int32))
+    assert got.dtype == np.int32 and got.shape == shape
+    np.testing.assert_array_equal(got, want)
+
+
+def _oracle_cols(self, col_ids):
+    """`SupportRemap.cols` through the oracle (the table's finite entries
+    are the sorted support)."""
+    return _searchsorted_cols(np.flatnonzero(self.table < self.n_hat),
+                              col_ids)
+
+
+@pytest.mark.parametrize("leg", ["batch", "chunk"])
+@pytest.mark.parametrize("word0", [False, True])
+def test_streaming_gram_table_remap_matches_oracle_cols(setup, monkeypatch,
+                                                        leg, word0):
+    """Both Gram legs hand the kernel the same local columns as the
+    searchsorted remap did, so ``g`` is equal bit for bit."""
+    _, store = setup
+    support = np.arange(0 if word0 else 1, 80, 3)
+    kw = dict(chunk_nnz=1024, chunk_rows=64)
+
+    def run():
+        acc = StreamingGram(support, chunk_rows=64)
+        if leg == "batch":
+            for mb in store.iter_megabatches(**kw, megabatch=4):
+                acc.update_csr_batch(mb)
+        else:
+            for ch in store.iter_chunks(**kw):
+                acc.update_csr(ch)
+        return np.asarray(acc.g), acc.count
+
+    g_table, n_table = run()
+    monkeypatch.setattr(SupportRemap, "cols", _oracle_cols)
+    g_oracle, n_oracle = run()
+    assert np.any(g_table != 0)
+    np.testing.assert_array_equal(g_table, g_oracle)
+    assert n_table == n_oracle
+
+
+@pytest.mark.parametrize("word0", [False, True])
+def test_gram_remap_counters_count_live_and_support_entries(setup, word0):
+    """``ingest.gram_entries`` counts live entries and
+    ``ingest.gram_support_entries`` those on the support — padded slots
+    (col 0) count in neither, even when word 0 is on the support."""
+    support = np.array([0, 3, 5]) if word0 else np.array([3, 5])
+    col = np.zeros((3, 8), np.int32)         # slot 2 is an empty tail slot
+    col[0, :5] = [0, 3, 4, 5, 9]             # 3 padded slots after them
+    col[1, :8] = [3, 3, 7, 0, 5, 2, 1, 6]
+    nnz = np.array([5, 8, 0])
+    vals = (col != 0).astype(np.float32)
+    vals[0, 0] = vals[1, 3] = 1.0            # the live word-0 entries
+    mb = CSRMegaBatch(values=vals, col_ids=col, seg_ids=np.zeros_like(col),
+                      row_offset=np.zeros(3, np.int64),
+                      n_rows=np.array([1, 1, 0], np.int32), nnz=nnz,
+                      n_chunks=2)
+    with metrics.use_registry() as reg:
+        StreamingGram(support, chunk_rows=64).update_csr_batch(mb)
+        assert reg.value("ingest.gram_entries") == 13
+        on = 2 + 3 + 2 * word0               # 3,5 | 3,3,5 | the two 0s
+        assert reg.value("ingest.gram_support_entries") == on
+    # a whole pass counts every stored entry, whatever the geometry
+    _, store = setup
+    with metrics.use_registry() as reg:
+        acc = StreamingGram(support, chunk_rows=64)
+        for b in store.iter_megabatches(chunk_nnz=1024, chunk_rows=64,
+                                        megabatch=4):
+            acc.update_csr_batch(b)
+        assert reg.value("ingest.gram_entries") == store.nnz
+        assert 0 < reg.value("ingest.gram_support_entries") < store.nnz
 
 
 # ------------------------------------------------------- pass economics

@@ -10,10 +10,13 @@ launches; the `ingest.shard_pass` / `solver.device_grid` spans and the
 `mesh.devices` gauge + merged `ingest.shard.*` lane counters appear; a
 mesh pass checkpoint resumes (and a device-topology change invalidates
 the fingerprint into a clean pass)."""
+import inspect
 import os
 import subprocess
 import sys
 import textwrap
+
+from test_ingest_pipeline import _searchsorted_cols
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -134,6 +137,47 @@ def test_mesh_pass_and_fit_parity_with_economics():
     print("MESH-OK")
     """)
     assert "MESH-OK" in out
+
+
+def test_mesh_gram_table_remap_matches_oracle_cols():
+    """The sharded Gram pass hands its step the same local columns through
+    the word->position table as through the searchsorted remap (G equal
+    bit for bit, with and without word 0 on the support), and counts every
+    stored entry once in ``ingest.gram_entries``."""
+    out = _run(inspect.getsource(_searchsorted_cols) + textwrap.dedent("""
+    import tempfile
+    from repro.data import make_corpus
+    from repro.data.bow import SupportRemap
+    from repro.obs import metrics
+    from repro.sparse import write_corpus
+    from repro.sparse.mesh_engine import mesh_reduced_covariance
+
+    corpus = make_corpus(400, 1200, topics={"t": ["a", "b", "c"]}, seed=5)
+    d = tempfile.mkdtemp()
+    store = write_corpus(corpus, d, shard_nnz=16_000)
+    geo = dict(chunk_nnz=1024, chunk_rows=64, megabatch=2)
+    table_cols = SupportRemap.cols
+
+    def oracle_cols(self, col_ids):
+        return _searchsorted_cols(np.flatnonzero(self.table < self.n_hat),
+                                  col_ids)
+
+    for support in (np.arange(0, 120, 2), np.arange(1, 120, 2)):
+        SupportRemap.cols = table_cols
+        with metrics.use_registry() as reg:
+            g_t = np.asarray(mesh_reduced_covariance(store, support,
+                                                     devices=4, **geo))
+            assert reg.value("ingest.gram_entries") == store.nnz
+            on = reg.value("ingest.gram_support_entries")
+            assert 0 < on < store.nnz
+        SupportRemap.cols = oracle_cols
+        g_o = np.asarray(mesh_reduced_covariance(store, support, devices=4,
+                                                 **geo))
+        assert np.any(g_t != 0)
+        np.testing.assert_array_equal(g_t, g_o)
+    print("REMAP-OK")
+    """))
+    assert "REMAP-OK" in out
 
 
 def test_device_grid_solve_parity_and_padding():
